@@ -42,8 +42,10 @@ resolves to the same on-disk entry and skips the cold negotiation.
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import dataclasses
+import functools
 import threading
 from typing import Any, Callable, Optional, Sequence
 
@@ -225,6 +227,7 @@ class FusedProgram:
         if mode not in Registry.MODES:
             raise ValueError(f"mode must be one of {Registry.MODES}")
         mode = resolve_auto(mode)
+        self.registry.dispatch_counts[(self.name, mode)] += 1
         if mode == "ref":
             # ref composes oracles on the original shapes; reject exactly
             # the operand lists the kernel path (validated inside
@@ -260,6 +263,11 @@ class Registry:
         # FusedProgram — and with it the Program's warm dispatch caches
         # (negotiated geometry, jitted pallas_call; DESIGN.md §12).
         self._fuse_cache: dict[tuple, "FusedProgram"] = {}
+        # Dispatches by (instruction or fused-chain name, resolved mode),
+        # counted when dispatched — once per trace under jit. A nonzero
+        # kernel count is the evidence that a path reached the Pallas
+        # kernel and not the oracle.
+        self.dispatch_counts: collections.Counter = collections.Counter()
 
     # -- registration --------------------------------------------------------
     def register(self, instr: Instruction, *, overwrite: bool = False) -> Instruction:
@@ -348,7 +356,7 @@ class Registry:
     # -- dispatch -------------------------------------------------------------
     @property
     def mode(self) -> str:
-        return getattr(self._tls, "mode", "ref")
+        return getattr(self._tls, "mode", "auto")
 
     @contextlib.contextmanager
     def use(self, mode: str):
@@ -363,6 +371,11 @@ class Registry:
             yield self
         finally:
             self._tls.mode = prev
+
+    def mode_dispatches(self, mode: str) -> int:
+        """Total dispatches resolved to ``mode`` so far."""
+        return sum(n for (_, m), n in self.dispatch_counts.items()
+                   if m == mode)
 
     def _resolve(self, instr: Instruction, mode: Optional[str]) -> str:
         requested = mode or self.mode
@@ -387,13 +400,43 @@ class Registry:
                 f"({instr.spec.scalar_in} scalar + {instr.spec.vector_in} "
                 f"vector), got {len(operands)}")
         m = self._resolve(instr, mode)
+        self.dispatch_counts[(name, m)] += 1
         if m == "ref":
             return instr.ref(*operands, **kw)
-        if m == "interpret":
-            return instr.kernel(*operands, interpret=True, **kw)
-        return instr.kernel(*operands, interpret=False, **kw)
+        return kernel_with_oracle_grad(
+            functools.partial(instr.kernel, interpret=(m == "interpret"),
+                              **kw),
+            functools.partial(instr.ref, **kw), operands)
 
     call = dispatch
+
+
+def kernel_with_oracle_grad(kernel: Callable, ref: Callable, operands):
+    """``kernel(*operands)`` forward, differentiated through ``ref``.
+
+    Pallas kernels have no backward rule, so a model trained with the
+    kernels dispatched would fail at ``jax.grad``; the oracles are plain
+    jnp and differentiate. Array operands are the differentiable inputs;
+    anything else (static widths, Python scalars) is closed over."""
+    where = [i for i, o in enumerate(operands) if isinstance(o, jax.Array)]
+
+    def bind(fn):
+        def call(*arrays):
+            ops = list(operands)
+            for i, a in zip(where, arrays):
+                ops[i] = a
+            return fn(*ops)
+        return call
+
+    def backward(arrays, ct):
+        out, vjp = jax.vjp(bind(ref), *arrays)
+        # the oracle may return a list where the kernel returns a tuple
+        return vjp(jax.tree.unflatten(jax.tree.structure(out),
+                                      jax.tree.leaves(ct)))
+
+    run = jax.custom_vjp(bind(kernel))
+    run.defvjp(lambda *a: (bind(kernel)(*a), a), backward)
+    return run(*(operands[i] for i in where))
 
 
 # The global ISA — the process-wide "decoder table".

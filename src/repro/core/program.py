@@ -122,6 +122,8 @@ class DispatchStats:
     geometry_misses: int = 0     # negotiations that ran the candidate loop
     call_builds: int = 0         # pallas_call callables constructed
     kernel_traces: int = 0       # times a fused kernel body was traced
+    kernel_launches: int = 0     # call_blocks launches compiled for the chip
+    interpret_launches: int = 0  # call_blocks launches in interpret mode
     rebucketed: int = 0          # warm buckets re-negotiated on cost drift
     batch_calls: int = 0         # coalesced call_batch launches
     batch_items: int = 0         # work items those coalesced launches served
@@ -849,6 +851,10 @@ class Program:
                 jax.ShapeDtypeStruct(vectors[0].shape, vectors[0].dtype)
                 for _ in range(last.n_vec_out))
 
+        if interpret:
+            DISPATCH_STATS.interpret_launches += 1
+        else:
+            DISPATCH_STATS.kernel_launches += 1
         # warm dispatch: one jitted pallas_call per operand signature —
         # a repeat call with the same shapes re-traces nothing.
         if scalar_items:
@@ -905,10 +911,8 @@ class Program:
 
         compiler_params = None
         if not interpret:
-            cp_cls = (getattr(pltpu, "CompilerParams", None)
-                      or getattr(pltpu, "TPUCompilerParams"))
             # rows are independent ("parallel"); cols carry state in order.
-            compiler_params = cp_cls(
+            compiler_params = pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary"))
 
         fn = jax.jit(pl.pallas_call(
@@ -1036,11 +1040,14 @@ class Program:
         repeat call does zero Python negotiation and zero kernel
         re-tracing (DESIGN.md §12).
         """
-        t0 = time.perf_counter() if _OBSERVED_HOOKS else None
         per_stage = self.split_operands(operands)
         flat_vecs = self._check_vectors(per_stage)
         ref_v = flat_vecs[0]
         n = ref_v.size
+        # traced calls (inside jit / shard_map) have no wall time to report
+        t0 = (time.perf_counter()
+              if _OBSERVED_HOOKS and not isinstance(ref_v, jax.core.Tracer)
+              else None)
 
         with _trace.span("dispatch", program=self.name, n_elems=int(n),
                          dtype=_dtype_name(ref_v.dtype),

@@ -47,13 +47,6 @@ def _pad_to(x: jax.Array, mult: int) -> tuple[jax.Array, int]:
     return x, pad
 
 
-def _axis_size(axis_name) -> int:
-    """Static size of a bound mesh axis (lax.axis_size is jax ≥ 0.6)."""
-    if hasattr(lax, "axis_size"):
-        return int(lax.axis_size(axis_name))
-    return int(lax.psum(1, axis_name))
-
-
 def compressed_ring_allreduce(x: jax.Array, axis_name: str,
                               qblock: int = 256) -> jax.Array:
     """Ring all-reduce (sum) with int8-per-hop payloads.
@@ -61,7 +54,7 @@ def compressed_ring_allreduce(x: jax.Array, axis_name: str,
     Must run inside shard_map/pmap with `axis_name` bound. Semantics match
     lax.psum(x, axis_name) up to quantisation error (tests bound it).
     """
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     if n == 1:
         return x
     shape, dtype = x.shape, x.dtype

@@ -23,28 +23,11 @@ import math
 from typing import Optional, Sequence
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec
+from jax.sharding import AbstractMesh, Mesh, NamedSharding, PartitionSpec
 
 Candidate = Optional[tuple]
 Rules = dict[str, Sequence[Candidate]]
 
-
-def shard_map(f, mesh: Mesh, in_specs, out_specs, check_vma=None):
-    """``jax.shard_map`` across jax versions.
-
-    jax ≥ 0.6 exposes ``jax.shard_map`` with a ``check_vma`` kwarg; on
-    0.4.x the API lives at ``jax.experimental.shard_map.shard_map`` and the
-    kwarg is named ``check_rep``. Every shard_map in this repo (and in the
-    subprocess test bodies) goes through this shim.
-    """
-    if hasattr(jax, "shard_map"):
-        kw = {} if check_vma is None else {"check_vma": check_vma}
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    kw = {} if check_vma is None else {"check_rep": check_vma}
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **kw)
 
 DEFAULT_RULES: Rules = {
     # -- activations ---------------------------------------------------------
@@ -77,7 +60,7 @@ DEFAULT_RULES: Rules = {
 
 
 def _mesh_axis_sizes(mesh: Mesh) -> dict[str, int]:
-    return dict(zip(mesh.axis_names, mesh.devices.shape))
+    return dict(mesh.shape)
 
 
 def shard_fit(dim_size: int, candidates: Sequence[Candidate], mesh: Mesh,
@@ -141,17 +124,15 @@ def tree_shardings(tree_logical, tree_shapes, mesh: Mesh,
 def constrain(x: jax.Array, logical_dims: Sequence[Optional[str]],
               mesh: Optional[Mesh] = None, rules: Optional[Rules] = None):
     """with_sharding_constraint by logical names (no-op outside a mesh)."""
-    mesh = mesh or _current_mesh()
-    if mesh is None or mesh.empty:
+    mesh = mesh or current_mesh()
+    if mesh is None:
         return x
     return jax.lax.with_sharding_constraint(
         x, logical_sharding(logical_dims, x.shape, mesh, rules))
 
 
-def _current_mesh() -> Optional[Mesh]:
-    try:
-        from jax._src import mesh as mesh_lib
-        m = mesh_lib.thread_resources.env.physical_mesh
-        return None if m.empty else m
-    except Exception:
-        return None
+def current_mesh() -> Optional[AbstractMesh]:
+    """The mesh set by ``jax.set_mesh`` (None outside one). Abstract, so
+    it answers inside ``jit`` as well as outside."""
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty else m

@@ -9,18 +9,19 @@ Dispatch (repro.core.isa.use):
     'ref'       — base core, no SIMD unit (paper's software baselines)
     'kernel'    — Pallas on TPU
     'interpret' — Pallas simulated on CPU (correctness tests)
-    'auto'      — kernel iff running on TPU
+    'auto'      — kernel iff running on TPU, ref elsewhere (the default)
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 
 from repro.core import isa
 from repro.core.isa import Instruction, OperandSpec
-from repro.core.stream import StreamConfig
+from repro.core.stream import LANES, StreamConfig, flatten_to_blocks
 # Shared operand shape normalisation — one entry path for every op (was
 # duplicated here and in stream_copy.py; see core/stream.py).
 from repro.core.stream import as_rows as _as_rows
@@ -38,13 +39,23 @@ from . import topk as _tk
 # c2_sort
 # ---------------------------------------------------------------------------
 
+def _lane_dense(width: int) -> int:
+    """Row width of the flat 2D view the sorting kernels run on: whole
+    ``width``-chunks, at least two lane rows wide."""
+    return max(width, 2 * LANES)
+
+
 def _sort_kernel(x, width: int = 8, descending: bool = False, *,
                  interpret: bool = False):
-    x2d, lead = _as_rows(x, x.shape[-1])
-    x2d, r = _pad_rows(x2d)
+    # chunks never straddle rows, so the flat element order is a sequence
+    # of whole chunks: sort it as lane-dense rows whatever the row length.
+    if x.shape[-1] % width:
+        raise ValueError(f"last dim {x.shape[-1]} % width {width} != 0")
+    cols = _lane_dense(width)
+    x2d, n = flatten_to_blocks(x, cols)
     out = _sn.sort_chunks_pallas(x2d, width=width, descending=descending,
-                                 interpret=interpret)
-    return out[:r].reshape(*lead, x.shape[-1])
+                                 block_cols=cols, interpret=interpret)
+    return out.reshape(-1)[:n].reshape(x.shape)
 
 
 isa.register(Instruction(
@@ -68,13 +79,16 @@ def sort_chunks(x, width: int = 8, descending: bool = False, mode=None):
 
 def _merge_kernel(a, b, width=None, *, interpret: bool = False):
     w = width or a.shape[-1]
-    a2, lead = _as_rows(a, a.shape[-1])
-    b2, _ = _as_rows(b, b.shape[-1])
-    a2, r = _pad_rows(a2)
-    b2, _ = _pad_rows(b2)
-    lo, hi = _sn.merge_sorted_pallas(a2, b2, width=w, interpret=interpret)
-    return (lo[:r].reshape(*lead, a.shape[-1]),
-            hi[:r].reshape(*lead, a.shape[-1]))
+    if a.shape != b.shape or a.shape[-1] % w:
+        raise ValueError(f"operands {a.shape}, {b.shape} must match and "
+                         f"hold whole {w}-chunks")
+    cols = _lane_dense(w)
+    a2, n = flatten_to_blocks(a, cols)
+    b2, _ = flatten_to_blocks(b, cols)
+    lo, hi = _sn.merge_sorted_pallas(a2, b2, width=w, block_cols=cols,
+                                     interpret=interpret)
+    return (lo.reshape(-1)[:n].reshape(a.shape),
+            hi.reshape(-1)[:n].reshape(a.shape))
 
 
 isa.register(Instruction(
@@ -95,16 +109,24 @@ def merge_sorted(a, b, width=None, mode=None):
 # c3_prefixsum
 # ---------------------------------------------------------------------------
 
+def _scan_blocks(rows: int, cols: int) -> tuple[int, int]:
+    """Block of a carried scan over (rows, cols), rows a multiple of 8:
+    the widest power-of-two column block up to 512 dividing cols, and as
+    many rows as keep one lane-padded f32 block near 512 KiB. A scan over
+    few columns (the SSD chunk axis) would otherwise pay a grid step for
+    every 8 rows."""
+    bc = next(c for c in (512, 256, 128, 64, 32, 16, 8, 4, 2, 1)
+              if cols % c == 0)
+    lanes = -(-bc // LANES) * LANES
+    return math.gcd(rows, (512 << 10) // (4 * lanes)), bc
+
+
 def _prefix_kernel(x, *, interpret: bool = False):
     x2d, lead = _as_rows(x, x.shape[-1])
     x2d, r = _pad_rows(x2d)
-    cols = x2d.shape[1]
-    bc = cols
-    for cand in (512, 256, 128, 64, 32, 16, 8, 4, 2, 1):
-        if cols % cand == 0:
-            bc = cand
-            break
-    out = _ps.prefix_sum_pallas(x2d, block_cols=bc, interpret=interpret)
+    br, bc = _scan_blocks(*x2d.shape)
+    out = _ps.prefix_sum_pallas(x2d, block_rows=br, block_cols=bc,
+                                interpret=interpret)
     return out[:r].reshape(*lead, x.shape[-1])
 
 
@@ -136,13 +158,9 @@ def _chunkscan_kernel(a, b, *, interpret: bool = False):
     b2, _ = _as_rows(b, b.shape[-1])
     a2, r = _pad_rows(a2)
     b2, _ = _pad_rows(b2)
-    cols = a2.shape[1]
-    bc = cols
-    for cand in (512, 256, 128, 64, 32, 16, 8, 4, 2, 1):
-        if cols % cand == 0:
-            bc = cand
-            break
-    out = _ps.chunk_scan_pallas(a2, b2, block_cols=bc, interpret=interpret)
+    br, bc = _scan_blocks(*a2.shape)
+    out = _ps.chunk_scan_pallas(a2, b2, block_rows=br, block_cols=bc,
+                                interpret=interpret)
     return out[:r].reshape(*lead, a.shape[-1])
 
 
@@ -161,9 +179,8 @@ def chunk_scan(a, b, mode=None):
 
 
 def _chunkscan_state_kernel(a, b, axis: int = 1, *, interpret: bool = False):
-    # kernel path: broadcast decay to state rank, scan along last axis.
-    # (On TPU this runs per-shard under shard_map; the ref path keeps the
-    # broadcast symbolic, which is what the sharded model path uses.)
+    # kernel path: broadcast decay to state rank, scan along last axis
+    # (the model calls it per shard under shard_map, models/ssm.py).
     extra = b.ndim - a.ndim
     ab = jnp.broadcast_to(a.reshape(a.shape + (1,) * extra), b.shape)
     ab = jnp.moveaxis(ab, axis, -1)
@@ -242,13 +259,6 @@ def stream_triad(a, b, s, mode=None):
 
 def _topk_kernel(x, k: int, *, interpret: bool = False):
     x2d, lead = _as_rows(x, x.shape[-1])
-    n = x2d.shape[1]
-    npow = 1 << (n - 1).bit_length()
-    if npow != n:
-        fill = jnp.finfo(x.dtype).min if jnp.issubdtype(x.dtype, jnp.floating) \
-            else jnp.iinfo(x.dtype).min
-        x2d = jnp.concatenate(
-            [x2d, jnp.full((x2d.shape[0], npow - n), fill, x.dtype)], axis=1)
     x2d, r = _pad_rows(x2d)
     vals, idx = _tk.topk_pallas(x2d, k, interpret=interpret)
     return (vals[:r].reshape(*lead, k), idx[:r].reshape(*lead, k))
@@ -299,13 +309,15 @@ def flash_attention(q, k, v, causal=True, scale=None, mode=None):
     # The ISA operand budget counts register *names*; K and V stream from the
     # same base address pair (S'-style), so they count as one vector source —
     # hence manual dispatch here rather than isa.call's 2-operand check.
-    mode = mode or isa.current_mode()
-    if mode == "auto":
-        mode = "kernel" if jax.default_backend() == "tpu" else "ref"
+    mode = isa.resolve_auto(mode or isa.current_mode())
+    isa.registry.dispatch_counts[("c6_flashattn", mode)] += 1
     if mode == "ref":
         return ref.flash_attention(q, k, v, causal=causal, scale=scale)
-    return _flashattn_kernel(q, k, v, causal=causal, scale=scale,
-                             interpret=(mode == "interpret"))
+    return isa.kernel_with_oracle_grad(
+        functools.partial(_flashattn_kernel, causal=causal, scale=scale,
+                          interpret=(mode == "interpret")),
+        functools.partial(ref.flash_attention, causal=causal, scale=scale),
+        (q, k, v))
 
 
 # ---------------------------------------------------------------------------

@@ -9,8 +9,8 @@ destinations (the 6-operand encoding is what makes it one instruction).
 
 TPU adaptation (DESIGN.md §2): each CAS layer is a vectorised
 compare-and-select between a lane and its XOR-partner lane. Partner
-indices are *static* per layer, so `jnp.take` lowers to lane shuffles on
-the VPU — the whole network fuses into ONE kernel (one "instruction"),
+indices are *static* per layer, so each exchange is two lane rotations
+and a select — the whole network fuses into ONE kernel (one "instruction"),
 versus the ~13-instruction min/max/shuffle sequences of fixed SIMD ISAs
 the paper counts in §6. Rows stream through the grid back-to-back, the
 pipelining the paper gets from its `c1_cycles` shift registers.
@@ -35,37 +35,41 @@ def _check_pow2(w: int, what: str) -> None:
 
 
 # ---------------------------------------------------------------------------
-# The network itself (shared by kernel bodies; built from static numpy index
-# math so every layer is shuffle + select — no data-dependent control flow).
+# The network itself (shared by kernel bodies; built from static lane
+# rotations and iota masks so every layer is shuffle + select on the flat
+# lane axis — no reversal, no lane-splitting reshape, no data-dependent
+# control flow). Chunks of ``width`` lanes sort independently; a chunk's
+# local lane index is the low log2(width) bits of the flat lane index.
+#
+# ``roll`` rotates along an axis with jnp.roll semantics: ``jnp.roll``
+# (the default) runs anywhere, kernel bodies pass ``pltpu.roll`` (the
+# VPU/XLU lane rotation).
 # ---------------------------------------------------------------------------
 
-def _swap_blocks(x: jax.Array, j: int) -> jax.Array:
-    """Value at lane XOR j — as a static reshape+reverse (a lane shuffle on
-    the VPU; no gather, no captured index tables)."""
-    *lead, w = x.shape
-    xr = x.reshape(*lead, w // (2 * j), 2, j)
-    return xr[..., ::-1, :].reshape(*lead, w)
+def _partner(x: jax.Array, j: int, roll=jnp.roll) -> jax.Array:
+    """Value at lane XOR j along the last axis: two static rotations and
+    a select on lane bit j."""
+    w = x.shape[-1]
+    ax = x.ndim - 1
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, ax)
+    above = roll(x, w - j, ax)              # x[lane + j]
+    below = roll(x, j, ax)                  # x[lane - j]
+    return jnp.where((lane & j) == 0, above, below)
 
 
-def _cas_layer(keys: jax.Array, payload: Optional[jax.Array],
-               j: int, k: int, descending: bool):
-    """One compare-and-swap layer: partner = lane XOR j, direction from k."""
-    lane = jax.lax.broadcasted_iota(jnp.int32, keys.shape, keys.ndim - 1)
-    lower = (lane & j) == 0                     # partner = lane^j → lower iff bit j unset
-    asc = (lane & k) == 0                       # ascending sub-block?
-    keep_lo = (asc != lower) if descending else (asc == lower)
-
-    kp = _swap_blocks(keys, j)
+def _exchange(keys, kp, payload, pp, lower, keep_lo, descending: bool):
+    """Compare-and-select of each lane with its partner value ``kp``:
+    ``lower`` marks the lane that holds the lower slot of its pair,
+    ``keep_lo`` that the slot keeps the pair's lower-ordered element."""
     lt = keys < kp
     eq = keys == kp
     if payload is None:
-        self_is_lo = lt | (eq & lower)          # lane tiebreak (keys only)
+        self_is_lo = lt | (eq & lower)      # lane tiebreak (keys only)
         take_self = keep_lo == self_is_lo
         return jnp.where(take_self, keys, kp), None
     # With payload, ties need a lane-independent total order so equal keys
     # emerge in ascending-payload order (= lax.top_k tie semantics for the
     # descending sort used by c5_topk).
-    pp = _swap_blocks(payload, j)
     tie = (payload > pp) if descending else (payload < pp)
     self_is_lo = lt | (eq & tie)
     take_self = keep_lo == self_is_lo
@@ -73,32 +77,80 @@ def _cas_layer(keys: jax.Array, payload: Optional[jax.Array],
             jnp.where(take_self, payload, pp))
 
 
+def _cas_layer(keys: jax.Array, payload: Optional[jax.Array],
+               j: int, k: int, descending: bool, width: int, roll):
+    """One compare-and-swap layer: partner = lane XOR j, direction from
+    the chunk-local lane bit k (k ≥ width → the whole chunk one way)."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, keys.shape, keys.ndim - 1)
+    lower = (lane & j) == 0                     # partner = lane^j → lower iff bit j unset
+    asc = (lane & (k & (width - 1))) == 0       # ascending sub-block?
+    keep_lo = (asc != lower) if descending else (asc == lower)
+    kp = _partner(keys, j, roll)
+    pp = None if payload is None else _partner(payload, j, roll)
+    return _exchange(keys, kp, payload, pp, lower, keep_lo, descending)
+
+
+def _chunk_width(keys: jax.Array, width: Optional[int], what: str) -> int:
+    width = width or keys.shape[-1]
+    _check_pow2(width, what)
+    if keys.shape[-1] % width:
+        raise ValueError(f"last dim {keys.shape[-1]} % {what} {width} != 0")
+    return width
+
+
 def bitonic_sort_network(keys: jax.Array, payload: Optional[jax.Array] = None,
-                         descending: bool = False):
-    """Full bitonic sort along the last axis (width = static power of 2)."""
-    w = keys.shape[-1]
-    _check_pow2(w, "sort width")
+                         descending: bool = False,
+                         width: Optional[int] = None, roll=jnp.roll):
+    """Bitonic sort of every ``width``-chunk of the last axis (width: a
+    static power of 2; default the whole axis)."""
+    width = _chunk_width(keys, width, "sort width")
     k = 2
-    while k <= w:
+    while k <= width:
         j = k // 2
         while j >= 1:
-            keys, payload = _cas_layer(keys, payload, j, k, descending)
+            keys, payload = _cas_layer(keys, payload, j, k, descending,
+                                       width, roll)
             j //= 2
         k *= 2
     return (keys, payload) if payload is not None else keys
 
 
 def bitonic_merge_network(keys: jax.Array, payload: Optional[jax.Array] = None,
-                          descending: bool = False):
-    """Merge stages only (`c1_merge`): input already bitonic along last axis."""
-    w = keys.shape[-1]
-    _check_pow2(w, "merge width")
-    j = w // 2
+                          descending: bool = False,
+                          width: Optional[int] = None, roll=jnp.roll):
+    """Merge stages only: every ``width``-chunk already bitonic."""
+    width = _chunk_width(keys, width, "merge width")
+    j = width // 2
     while j >= 1:
-        # k = 2w → every sub-block ascending (or descending).
-        keys, payload = _cas_layer(keys, payload, j, 2 * w, descending)
+        # k = 2·width → every chunk one direction.
+        keys, payload = _cas_layer(keys, payload, j, 2 * width, descending,
+                                   width, roll)
         j //= 2
     return (keys, payload) if payload is not None else keys
+
+
+def merge_sorted_network(a: jax.Array, b: jax.Array, width: int,
+                         descending: bool = False, roll=jnp.roll):
+    """``c1_merge``: per ``width``-chunk, merge sorted a with sorted b into
+    the lower (first) and upper (second) halves of their sorted union.
+
+    This is the 2·width bitonic merge of ``a ++ reversed(b)``, computed
+    without building either: the reversal is lane XOR (width-1), composed
+    from partner exchanges, and the first layer pairs lane i of ``a`` with
+    lane i of the reversed ``b``; the remaining layers stay within each
+    half."""
+    width = _chunk_width(a, width, "merge width")
+    rb = b
+    j = 1
+    while j < width:
+        rb = _partner(rb, j, roll)
+        j *= 2
+    lo, _ = _exchange(a, rb, None, None, True, not descending, descending)
+    hi, _ = _exchange(rb, a, None, None, False, descending, descending)
+    return (bitonic_merge_network(lo, descending=descending, width=width,
+                                  roll=roll),
+            bitonic_merge_network(hi, descending=descending, width=width,
+                                  roll=roll))
 
 
 def n_cas_layers(width: int) -> int:
@@ -112,11 +164,8 @@ def n_cas_layers(width: int) -> int:
 # ---------------------------------------------------------------------------
 
 def _sort_body(width: int, descending: bool, x_ref, o_ref):
-    x = x_ref[...]
-    r, c = x.shape
-    xr = x.reshape(r, c // width, width)
-    s = bitonic_sort_network(xr, descending=descending)
-    o_ref[...] = s.reshape(r, c)
+    o_ref[...] = bitonic_sort_network(x_ref[...], descending=descending,
+                                      width=width, roll=pltpu.roll)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -151,15 +200,10 @@ def sort_chunks_pallas(x: jax.Array, *, width: int = 8,
 # ---------------------------------------------------------------------------
 
 def _merge_body(width: int, descending: bool, a_ref, b_ref, lo_ref, hi_ref):
-    a = a_ref[...]
-    b = b_ref[...]
-    r, c = a.shape
-    ar = a.reshape(r, c // width, width)
-    br = b.reshape(r, c // width, width)[..., ::-1]   # reversed → bitonic
-    both = jnp.concatenate([ar, br], axis=-1)
-    s = bitonic_merge_network(both, descending=descending)
-    lo_ref[...] = s[..., :width].reshape(r, c)
-    hi_ref[...] = s[..., width:].reshape(r, c)
+    lo, hi = merge_sorted_network(a_ref[...], b_ref[...], width,
+                                  descending=descending, roll=pltpu.roll)
+    lo_ref[...] = lo
+    hi_ref[...] = hi
 
 
 @functools.partial(jax.jit, static_argnames=(

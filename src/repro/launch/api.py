@@ -206,7 +206,7 @@ def build_cell(cfg: ModelConfig, shape: ShapeConfig, mesh,
 
 def lower_cell(cfg: ModelConfig, shape: ShapeConfig, mesh, **kw):
     fn, args, in_sh, out_sh, donate = build_cell(cfg, shape, mesh, **kw)
-    with mesh:
+    with jax.set_mesh(mesh):
         jitted = jax.jit(fn, in_shardings=in_sh, out_shardings=out_sh,
                          donate_argnums=donate)
         return jitted.lower(*args)

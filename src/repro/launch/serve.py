@@ -48,6 +48,13 @@ from repro.models import model as M
 from repro.models.params import abstract_params, logical_axes
 
 
+@dataclasses.dataclass
+class ServeResult:
+    tokens: np.ndarray          # (batch, gen) generated ids, greedy or sampled
+    prefill_logits: jax.Array   # (batch, vocab) logits at the last prompt position
+    compile_s: float            # seconds compiling the prefill and decode steps
+
+
 def grow_cache_fn(cfg, prefill_len, capacity):
     """Close over the static sizes so the cache growth can be jitted."""
     def f(cache):
@@ -63,6 +70,11 @@ def main(argv=None):
     p.add_argument("--prompt-len", type=int, default=64)
     p.add_argument("--gen", type=int, default=32)
     p.add_argument("--model-parallel", type=int, default=1)
+    p.add_argument("--devices", type=int, default=None, metavar="N",
+                   help="serve on the first N devices (default: all)")
+    p.add_argument("--dtype", default=None, choices=("bfloat16", "float32"),
+                   help="parameter and activation dtype (default: the "
+                        "config's)")
     p.add_argument("--temperature", type=float, default=0.0)
     p.add_argument("--seed", type=int, default=0)
     p.add_argument("--sched", action="store_true",
@@ -152,13 +164,17 @@ def main(argv=None):
     if args.reduced:
         cfg = cfg.reduced()
     cfg = dataclasses.replace(cfg, attn_impl="chunked")
+    if args.dtype:
+        cfg = dataclasses.replace(cfg, param_dtype=args.dtype,
+                                  act_dtype=args.dtype)
 
-    mesh = make_elastic_mesh(model_parallel=args.model_parallel)
+    mesh = make_elastic_mesh(args.devices,
+                             model_parallel=args.model_parallel)
     print(f"mesh {mesh_name(mesh)}")
     capacity = args.prompt_len + args.gen
     rng = jax.random.PRNGKey(args.seed)
 
-    with mesh:
+    with jax.set_mesh(mesh):
         params_sh = tree_shardings(logical_axes(cfg), abstract_params(cfg),
                                    mesh)
         params = jax.jit(lambda r: M.init_params(cfg, r),
@@ -170,8 +186,16 @@ def main(argv=None):
         decode = jax.jit(
             lambda pp, c, t, pos: M.decode_step(cfg, pp, c, t, pos))
 
+        # compile ahead of the first call, so the timings below exclude it
+        # (the jitted functions reuse these executables)
+        batch_in = {"tokens": prompts}
         t0 = time.time()
-        logits, cache = prefill(params, {"tokens": prompts})
+        prefill.lower(params, batch_in).compile()
+        t_compile = time.time() - t0
+
+        t0 = time.time()
+        logits, cache = prefill(params, batch_in)
+        prefill_logits = logits
         cache = jax.jit(grow_cache_fn(cfg, args.prompt_len, capacity))(cache)
         jax.block_until_ready(logits)
         t_prefill = time.time() - t0
@@ -182,6 +206,12 @@ def main(argv=None):
         out_tokens = []
         tok = sample(logits, rng, args.temperature)
         out_tokens.append(np.asarray(tok))
+        if args.gen > 1:
+            t0 = time.time()
+            decode.lower(params, cache, tok,
+                         jnp.int32(args.prompt_len)).compile()
+            t_compile += time.time() - t0
+        print(f"compiled prefill + decode in {t_compile:.1f} s")
         if args.sched:
             gen, dt = _decode_scheduled(args, decode, sample, params, cache,
                                         tok, rng, out_tokens)
@@ -219,7 +249,8 @@ def main(argv=None):
             print(f"holding metrics endpoint {args.metrics_hold:.0f}s",
                   flush=True)
             time.sleep(args.metrics_hold)
-        return gen
+        return ServeResult(tokens=gen, prefill_logits=prefill_logits,
+                           compile_s=t_compile)
 
 
 def _decode_scheduled(args, decode, sample_fn, params, cache, tok, rng,
@@ -324,4 +355,6 @@ def sample(logits, rng, temperature):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

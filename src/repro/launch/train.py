@@ -26,7 +26,7 @@ from repro.checkpoint.ckpt import latest_step
 from repro.configs import SHAPES, get_config
 from repro.data import SyntheticLMData, TokenFileData, make_global_batch
 from repro.distributed.collectives import compressed_ring_allreduce
-from repro.distributed.sharding import shard_map, tree_shardings
+from repro.distributed.sharding import tree_shardings
 from repro.launch import api
 from repro.launch.mesh import make_elastic_mesh, mesh_name
 
@@ -46,7 +46,7 @@ def make_pod_sync(mesh):
     spec = P()  # params replicated over pod in-spec handled per-leaf below
 
     def sync(params):
-        return shard_map(
+        return jax.shard_map(
             avg, mesh=mesh,
             in_specs=(jax.tree.map(lambda _: spec, params),),
             out_specs=jax.tree.map(lambda _: spec, params),
@@ -94,7 +94,7 @@ def main(argv=None):
     batch_sh = tree_shardings(api.batch_logical(cfg, shape), batch_abs, mesh)
 
     step_fn = api.make_train_step(cfg, grad_accum=args.grad_accum)
-    with mesh:
+    with jax.set_mesh(mesh):
         jitted = jax.jit(step_fn, in_shardings=(state_sh, batch_sh),
                          out_shardings=(state_sh, None), donate_argnums=0)
 
@@ -150,4 +150,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -31,7 +31,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
-from repro.distributed.sharding import _current_mesh, shard_map
+from repro.distributed.sharding import current_mesh
 from repro.kernels import ops as kops
 
 
@@ -176,7 +176,7 @@ def _moe_sharded(cfg: ModelConfig, p: dict, x: jax.Array, mesh,
                                          tp_axis, n_ep)
         return out.reshape(b_l, s, d), aux
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(batch_axes if batch_axes else None, None, None), wspecs),
         out_specs=(P(batch_axes if batch_axes else None, None, None), P()),
@@ -187,7 +187,7 @@ def _moe_sharded(cfg: ModelConfig, p: dict, x: jax.Array, mesh,
 
 def moe_layer(cfg: ModelConfig, p: dict, x: jax.Array):
     """x: (B, S, D) → (out (B,S,D), aux load-balance loss)."""
-    mesh = _current_mesh()
+    mesh = current_mesh()
     if mesh is None or cfg.moe_impl == "dense":
         return _moe_dense(cfg, p, x)
     use_ep = (cfg.moe_impl == "ep"

@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.distributed.sharding import constrain
+from repro.distributed.sharding import constrain, current_mesh, logical_spec
 from repro.kernels import ops as kops
 
 from .layers import rmsnorm
@@ -118,7 +118,7 @@ def ssd_forward(cfg: ModelConfig, p: dict, u: jax.Array,
     # inter-chunk recurrence — the paper's carried scan (c4_statescan):
     # shared per-(B,C,H) decay, (P,N) state payload, scan along chunks.
     a_chunk = jnp.exp(cum[:, :, -1, :])                    # (B,C,H)
-    run = kops.chunk_scan_state(a_chunk, states, axis=1)   # (B,C,H,P,N)
+    run = _state_scan(a_chunk, states)                     # (B,C,H,P,N)
     prev = jnp.concatenate(
         [jnp.zeros_like(run[:, :1]), run[:, :-1]], axis=1)  # state before c
 
@@ -136,6 +136,22 @@ def ssd_forward(cfg: ModelConfig, p: dict, u: jax.Array,
     if return_state:
         return out, (run[:, -1], conv_cache)   # state after last chunk
     return out
+
+
+def _state_scan(a_chunk: jax.Array, states: jax.Array) -> jax.Array:
+    """c4_statescan over chunks, per shard under a mesh: XLA cannot
+    partition a Pallas call, so each device scans its own batch × head
+    block (the scan runs along chunks, which are never sharded)."""
+    mesh = current_mesh()
+    if mesh is None:
+        return kops.chunk_scan_state(a_chunk, states, axis=1)
+    a_spec = logical_spec(("batch", None, "ssm_heads"), a_chunk.shape, mesh)
+    s_spec = logical_spec(("batch", None, "ssm_heads", None, None),
+                          states.shape, mesh)
+    return jax.shard_map(
+        lambda a, s: kops.chunk_scan_state(a, s, axis=1), mesh=mesh,
+        in_specs=(a_spec, s_spec), out_specs=s_spec,
+        check_vma=False)(a_chunk, states)
 
 
 def ssd_decode(cfg: ModelConfig, p: dict, u: jax.Array,

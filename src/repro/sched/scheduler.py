@@ -210,17 +210,17 @@ def mesh_lane_count(mesh, axis) -> int:
 
 
 def sharded_program_call(fused, operand_tuples, mesh, axis="parts",
-                         chunk_call=None):
+                         mode: Optional[str] = None):
     """Run N independent same-structure requests across a device mesh.
 
     The ``shard_map``-over-parts mapping (ROADMAP item): operands of the
     N requests are stacked along a fresh leading ``parts`` axis, sharded
     over ``mesh``'s ``axis`` devices, and each device runs its chunk of
-    requests through the program's oracle composition (plain-jax, so it
-    shard_maps on every backend; pass ``chunk_call`` to substitute e.g. a
-    kernel-path callable on TPU). N is padded up to a multiple of the
-    axis size by replicating the first request; padding results are
-    dropped. Returns the per-request results in order.
+    requests through the program in dispatch ``mode`` (default: the
+    registry's — the fused kernel on TPU, the oracle elsewhere). N is
+    padded up to a multiple of the axis size by replicating the first
+    request; padding results are dropped. Returns the per-request results
+    in order.
 
     ``axis`` may be a tuple of axis names (a *multi-host lane mesh*,
     DESIGN.md §18): the stacked parts axis shards over the product of
@@ -229,8 +229,6 @@ def sharded_program_call(fused, operand_tuples, mesh, axis="parts",
     mapping when each host drains its own HBM channel.
     """
     from jax.sharding import PartitionSpec as P
-
-    from repro.distributed.sharding import shard_map
 
     if not isinstance(fused, FusedProgram):
         raise TypeError("sharded_program_call needs a FusedProgram "
@@ -247,18 +245,19 @@ def sharded_program_call(fused, operand_tuples, mesh, axis="parts",
     n_ops = fused.program.n_inputs
     stacked = [jnp.stack([jnp.asarray(it[k]) for it in items])
                for k in range(n_ops)]
-    run_one = chunk_call or fused._ref
     spec = P(axes[0] if len(axes) == 1 else axes)
 
     def shard_fn(*ops):
-        outs = [run_one(*(o[j] for o in ops)) for j in range(chunk)]
+        outs = [fused(*(o[j] for o in ops), mode=mode)
+                for j in range(chunk)]
         if isinstance(outs[0], tuple):
             return tuple(jnp.stack([o[i] for o in outs])
                          for i in range(len(outs[0])))
         return jnp.stack(outs)
 
-    f = shard_map(shard_fn, mesh, in_specs=(spec,) * n_ops,
-                  out_specs=spec)
+    # check_vma=False: a pallas_call declares no per-axis variance
+    f = jax.shard_map(shard_fn, mesh=mesh, in_specs=(spec,) * n_ops,
+                      out_specs=spec, check_vma=False)
     out = f(*stacked)
     if isinstance(out, tuple):
         return [tuple(o[k] for o in out) for k in range(n_real)]
@@ -490,7 +489,7 @@ class Scheduler:
                 and batch.key is not None:
             return sharded_program_call(
                 batch.target, [it.operands for it in batch.items],
-                self.mesh, axis=self.mesh_axis)
+                self.mesh, axis=self.mesh_axis, mode=mode)
         # coalescing is a kernel-path mechanism (one stacked pallas_call);
         # ref-mode dispatch composes oracles per item instead.
         if batch.coalesced and prog is not None and mode != "ref":
@@ -501,9 +500,11 @@ class Scheduler:
             if isinstance(it.target, (FusedProgram, Plan)):
                 outs.append(it.target(*it.operands, mode=mode))
             elif program_of(it.target) is not None:
-                # a bare Program has no oracle: kernel or interpret only
-                outs.append(it.target(*it.operands,
-                                      interpret=(mode != "kernel")))
+                # a bare Program has no oracle: the kernel on the chip,
+                # the interpreter anywhere else
+                outs.append(it.target(
+                    *it.operands,
+                    interpret=jax.default_backend() != "tpu"))
             else:
                 outs.append(it.target(*it.operands))
         return outs

@@ -18,6 +18,7 @@ def run_with_devices(body: str, n: int = 8) -> str:
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={n}"
         os.environ["JAX_PLATFORMS"] = "cpu"
         import sys; sys.path.insert(0, {SRC!r})
+        from repro.launch.mesh import make_mesh
     """) + textwrap.dedent(body)
     out = subprocess.run([sys.executable, "-c", prog], capture_output=True,
                          text=True, timeout=600)
@@ -31,13 +32,12 @@ def test_compressed_ring_allreduce_matches_psum():
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
         from repro.distributed.collectives import compressed_ring_allreduce
-        from repro.distributed.sharding import shard_map
-        mesh = jax.make_mesh((8,), ("d",))
+        mesh = make_mesh((8,), ("d",))
         x = jnp.asarray(np.random.default_rng(0).standard_normal((8, 4096)),
                         jnp.float32)
         def body(xl):
             return compressed_ring_allreduce(xl, "d"), jax.lax.psum(xl, "d")
-        got, want = jax.jit(shard_map(
+        got, want = jax.jit(jax.shard_map(
             body, mesh=mesh, in_specs=P("d"), out_specs=(P("d"), P("d")),
             check_vma=False))(x)
         err = float(jnp.max(jnp.abs(got - want)))
@@ -78,8 +78,7 @@ def test_gpipe_forward_matches_sequential():
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
         from repro.distributed.pipeline import gpipe_forward
-        from repro.distributed.sharding import shard_map
-        mesh = jax.make_mesh((4,), ("stage",))
+        mesh = make_mesh((4,), ("stage",))
         S, M, D = 4, 6, 16
         rng = np.random.default_rng(0)
         w = jnp.asarray(rng.standard_normal((S, D, D)) / np.sqrt(D),
@@ -90,7 +89,7 @@ def test_gpipe_forward_matches_sequential():
         def run(w_all, mbs):
             out = gpipe_forward(stage, w_all, mbs, "stage", S)
             return jax.lax.psum(out, "stage")  # valid only on last stage
-        got = jax.jit(shard_map(run, mesh=mesh,
+        got = jax.jit(jax.shard_map(run, mesh=mesh,
             in_specs=(P("stage"), P()), out_specs=P(),
             check_vma=False))(w, mbs)
         want = mbs
@@ -111,19 +110,19 @@ def test_moe_ep_matches_dense_oracle():
         cfg = dataclasses.replace(
             get_config("kimi_k2_1t").reduced(),
             n_experts=8, top_k=2, capacity_factor=8.0)  # no drops
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         rng = jax.random.PRNGKey(0)
         p = init_params(cfg, rng)["layers"]["moe"]
         p = jax.tree.map(lambda x: x[0], p)  # one layer
         x = jax.random.normal(rng, (4, 8, cfg.d_model), jnp.float32)
         dense_out, aux_d = moe._moe_dense(cfg, p, x)
-        with mesh:
+        with jax.set_mesh(mesh):
             ep_out, aux_e = jax.jit(
                 lambda xx: moe._moe_sharded(cfg, p, xx, mesh, use_ep=True))(x)
         np.testing.assert_allclose(np.asarray(ep_out),
                                    np.asarray(dense_out),
                                    rtol=2e-3, atol=2e-3)
-        with mesh:
+        with jax.set_mesh(mesh):
             tp_out, _ = jax.jit(
                 lambda xx: moe._moe_sharded(cfg, p, xx, mesh, use_ep=False))(x)
         np.testing.assert_allclose(np.asarray(tp_out),
@@ -148,7 +147,7 @@ def test_sharded_train_step_matches_single_device():
         step = api.make_train_step(cfg)
         _, m1 = jax.jit(step)(jax.tree.map(jnp.copy, state),
                               jax.tree.map(jnp.copy, batch))
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         from repro.configs.base import SHAPES
         shp = dataclasses.replace(SHAPES["train_4k"], seq_len=32,
                                   global_batch=8)
@@ -156,7 +155,7 @@ def test_sharded_train_step_matches_single_device():
                                jax.eval_shape(lambda: state), mesh)
         b_sh = tree_shardings(api.batch_logical(cfg, shp),
                               jax.eval_shape(lambda: batch), mesh)
-        with mesh:
+        with jax.set_mesh(mesh):
             _, m2 = jax.jit(step, in_shardings=(st_sh, b_sh),
                             out_shardings=(st_sh, None))(state, batch)
         np.testing.assert_allclose(float(m1["loss"]), float(m2["loss"]),
@@ -171,11 +170,11 @@ def test_elastic_checkpoint_restore_other_mesh(tmp_path):
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.checkpoint import save_checkpoint, restore_sharded
         tree = {{"w": jnp.arange(64, dtype=jnp.float32).reshape(8, 8)}}
-        mesh8 = jax.make_mesh((8,), ("data",))
+        mesh8 = make_mesh((8,), ("data",))
         placed = jax.device_put(tree, NamedSharding(mesh8, P("data")))
         save_checkpoint({str(tmp_path)!r}, 5, placed)
         # restore onto a DIFFERENT mesh (4×2)
-        mesh42 = jax.make_mesh((4, 2), ("data", "model"))
+        mesh42 = make_mesh((4, 2), ("data", "model"))
         sh = {{"w": NamedSharding(mesh42, P("data", "model"))}}
         tmpl = {{"w": jax.ShapeDtypeStruct((8, 8), jnp.float32)}}
         got, m = restore_sharded({str(tmp_path)!r}, tmpl, sh)
@@ -191,10 +190,10 @@ def test_pod_sync_averages_params():
     run_with_devices("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.launch.train import make_pod_sync
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         sync = make_pod_sync(mesh)
         # per-pod divergent params (replicated within pod by construction)
-        with mesh:
+        with jax.set_mesh(mesh):
             p = {"w": jnp.ones((4, 256), jnp.float32)}
             out = sync(p)
         np.testing.assert_allclose(np.asarray(out["w"]), 1.0, rtol=1e-2)
@@ -225,4 +224,23 @@ def test_elastic_train_resume_smaller_mesh(tmp_path):
                         "--ckpt-every", "2", "--log-every", "2"])
         assert "resumed from step 4" in buf.getvalue(), buf.getvalue()
         print("OK resumed on smaller mesh")
+    """, n=4)
+
+
+def test_chip_smoke_four_device_phases():
+    """chip_smoke.py --chips 4's phases on four virtual devices, in
+    interpret mode: Mamba2 served on a (1, 4) mesh and a 4-lane
+    sharded_program_call, each against device 0."""
+    root = os.path.dirname(SRC)
+    run_with_devices(f"""
+        import sys; sys.path.insert(0, {root!r})
+        import chip_smoke as cs
+        argv = ["--arch", "mamba2-1.3b", "--reduced", "--batch", "2",
+                "--prompt-len", "64", "--gen", "4"]
+        rec = cs.phase_sharded_serve(argv, "interpret", 4)
+        assert rec["max_err"] <= cs.LOGIT_TOL_BF16, rec
+        assert rec["max_err_f32"] <= cs.LOGIT_TOL_F32, rec
+        rec = cs.phase_sharded_programs(4096, "interpret", 4)
+        assert rec["max_err"] == 0.0, rec
+        print("OK")
     """, n=4)

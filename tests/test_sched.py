@@ -32,6 +32,7 @@ from repro.core.burst_model import BurstModel
 from repro.core.program import Program
 from repro.graph import partition
 from repro.kernels.ops import c0_pipeline_graph
+from repro.launch.mesh import make_mesh
 from repro.memhier import TPU_V5E, contended_makespan, predict_program
 from repro.sched import (CostModel, RequestQueue, Scheduler, TraceRecorder,
                          coalesce_key, placements_match, replay,
@@ -498,7 +499,7 @@ class TestScheduler:
     def test_sharded_lanes_match_oracle(self):
         fused = isa.fuse("c0_scale", "c0_add")
         x, b = vecs(0, 1)
-        mesh = jax.make_mesh((1,), ("parts",))
+        mesh = make_mesh((1,), ("parts",))
         reqs = [(2.0, x, b), (3.0, b, x), (1.5, x, x)]
         outs = sharded_program_call(fused, reqs, mesh)
         for ops, got in zip(reqs, outs):
@@ -507,7 +508,7 @@ class TestScheduler:
                 rtol=1e-6)
 
     def test_sharded_scheduler_run(self):
-        mesh = jax.make_mesh((1,), ("parts",))
+        mesh = make_mesh((1,), ("parts",))
         q = RequestQueue()
         fused = isa.fuse("c0_scale", "c0_add")
         x, b = vecs(0, 1)

@@ -135,11 +135,8 @@ def test_token_file_data(tmp_path):
 # sharding rules (no mesh needed beyond 1 device: test the solver logic)
 # ---------------------------------------------------------------------------
 
-class FakeMesh:
-    axis_names = ("pod", "data", "model")
-
-    class devices:
-        shape = (2, 16, 16)
+# the two-pod production mesh, without devices
+FakeMesh = jax.sharding.AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def test_shard_fit_picks_first_divisible():

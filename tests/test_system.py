@@ -49,7 +49,8 @@ def test_serve_driver_generates():
         gen = serve.main(["--arch", "hymba-1.5b", "--reduced",
                           "--batch", "2", "--prompt-len", "32",
                           "--gen", "8"])
-    assert gen.shape == (2, 8)
+    assert gen.tokens.shape == (2, 8)
+    assert gen.prefill_logits.shape == (2, 512)
 
 
 def test_greedy_decode_is_deterministic():
@@ -59,7 +60,7 @@ def test_greedy_decode_is_deterministic():
         with contextlib.redirect_stdout(io.StringIO()):
             outs.append(serve.main(["--arch", "llama3-8b", "--reduced",
                                     "--batch", "1", "--prompt-len", "16",
-                                    "--gen", "6"]))
+                                    "--gen", "6"]).tokens)
     np.testing.assert_array_equal(outs[0], outs[1])
 
 
