@@ -1,0 +1,30 @@
+"""STREAM Scale, ``b = q·c``: the ``c0_scale`` instruction as a one-stage
+fused program, float32. One vector and one scalar in, one vector out."""
+import numpy as np
+
+from chipbench import harness
+
+VECTORS = 1
+KEYS = False
+NUMBER = "stream_rel_err"
+KERNELS = ("c0_program",)
+
+
+def target(n: int):
+    from repro.core import isa
+    return isa.fuse("c0_scale")
+
+
+def operands(vecs: tuple, scalar: float) -> tuple:
+    import jax.numpy as jnp
+    return (jnp.float32(scalar), vecs[0])
+
+
+def work(n: int) -> dict:
+    return {"c0_program": list(harness.load_module("work", "c0_program").work(
+        n=n, vec_in=1, vec_out=1, flops_per_elem=1))}
+
+
+def reference(ops: tuple, dtype) -> tuple:
+    q, c = (np.asarray(v).astype(dtype) for v in ops)
+    return ((q * c).astype(dtype),)
