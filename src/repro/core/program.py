@@ -59,7 +59,8 @@ Observability (DESIGN.md §15): the dispatch path emits structured
 spans — ``dispatch`` around every ``__call__``/``call_batch``,
 ``negotiate`` around a memo-miss sweep (outcome ``disk_hit`` vs
 ``sweep``), ``pallas_build`` around a cold jit build — through
-:mod:`repro.obs.trace` (no-ops when no tracer is active), and
+:mod:`repro.obs.trace` (no-ops when no tracer is installed and no
+profiler collects; written into the profiler trace when one does), and
 :data:`DISPATCH_STATS` is a thin view over registry-backed
 ``repro_dispatch_*_total`` counters in :mod:`repro.obs.metrics`;
 ``bench_hotpath`` gates the instrumented warm path at ≤ 3% overhead.
@@ -681,13 +682,25 @@ class Program:
             return hit
         # memo miss: everything below is span-worthy work (DESIGN.md
         # §15 — "negotiate" span, outcome disk_hit | sweep | no_fit).
-        _tr = _trace.ACTIVE
-        _sp = (_tr.start_span("negotiate", program=self.name,
-                              n_elems=int(n_elems),
-                              dtype=_dtype_name(dtype),
-                              bucket=_n_bucket(n_elems),
-                              fingerprint=_artifact.key_hash(key))
-               if _tr is not None else None)
+        # The verdict is raised after the span closes, so a no-fit
+        # span records its outcome and no error.
+        with (_trace.span("negotiate", program=self.name,
+                          n_elems=int(n_elems), dtype=_dtype_name(dtype),
+                          bucket=_n_bucket(n_elems),
+                          fingerprint=_artifact.key_hash(key))
+              if _trace.enabled() else _trace.NULL_SPAN) as sp:
+            verdict = self._negotiate_miss(key, model_fp, n_elems, dtype,
+                                           fresh, sp)
+        if verdict[0] == "no-fit":
+            raise ValueError(verdict[1])
+        return verdict
+
+    def _negotiate_miss(self, key, model_fp, n_elems: int, dtype,
+                        fresh: bool, sp):
+        """A memo miss: the disk artifact, else the candidate sweep.
+        Returns the geometry or the ``("no-fit", msg)`` verdict, and
+        stamps the outcome on the tracer's span ``sp`` (None when no
+        tracer records it)."""
         # in-process miss: consult the persistent artifact cache before
         # paying the candidate sweep (DESIGN.md §14). Token-fingerprinted
         # models are process-local and never share disk entries.
@@ -699,11 +712,9 @@ class Program:
             if loaded is not None:
                 DISPATCH_STATS.geometry_hits += 1
                 _cache_geometry(key, loaded)
-                if _sp is not None:
-                    _tr.finish(_sp, outcome="disk_hit",
-                               no_fit=loaded[0] == "no-fit")
-                if loaded[0] == "no-fit":
-                    raise ValueError(loaded[1])
+                if sp is not None:
+                    sp.attrs.update(outcome="disk_hit",
+                                    no_fit=loaded[0] == "no-fit")
                 return loaded
         DISPATCH_STATS.geometry_misses += 1
         block_rows = 1
@@ -739,17 +750,17 @@ class Program:
             _cache_geometry(key, verdict)
             if disk is not None:
                 disk.store("geom", key, _geometry_payload(verdict))
-            if _sp is not None:
-                _tr.finish(_sp, outcome="sweep", no_fit=True)
-            raise ValueError(msg)
+            if sp is not None:
+                sp.attrs.update(outcome="sweep", no_fit=True)
+            return verdict
         t, bc, cfg = best
         result = (block_rows, bc, cfg, t)
         _cache_geometry(key, result)
         if disk is not None:
             disk.store("geom", key, _geometry_payload(result))
-        if _sp is not None:
-            _tr.finish(_sp, outcome="sweep", block=[block_rows, bc],
-                       modeled_s=t)
+        if sp is not None:
+            sp.attrs.update(outcome="sweep", block=[block_rows, bc],
+                            modeled_s=t)
         return result
 
     # -- kernel emission ----------------------------------------------------

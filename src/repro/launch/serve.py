@@ -46,6 +46,7 @@ from repro.launch import api
 from repro.launch.mesh import make_elastic_mesh, mesh_name
 from repro.models import model as M
 from repro.models.params import abstract_params, logical_axes
+from repro.obs import trace as _trace
 
 
 @dataclasses.dataclass
@@ -152,9 +153,8 @@ def main(argv=None):
     tracer = None
     sampler = None
     if args.obs_trace or args.obs_tail:
-        from repro.obs import trace as obs_trace
-        tracer = obs_trace.Tracer()
-        obs_trace.set_tracer(tracer)
+        tracer = _trace.Tracer()
+        _trace.set_tracer(tracer)
         if args.obs_tail:
             from repro.obs.tail import TailSampler
             sampler = TailSampler(tracer, sample_rate=0.01,
@@ -348,10 +348,11 @@ def _decode_scheduled(args, decode, sample_fn, params, cache, tok, rng,
 
 
 def sample(logits, rng, temperature):
-    if temperature <= 0:
-        return jnp.argmax(logits, axis=-1)[:, None].astype(jnp.int32)
-    return jax.random.categorical(
-        rng, logits / temperature, axis=-1)[:, None].astype(jnp.int32)
+    with _trace.host_span("sample"):
+        if temperature <= 0:
+            return jnp.argmax(logits, axis=-1)[:, None].astype(jnp.int32)
+        return jax.random.categorical(
+            rng, logits / temperature, axis=-1)[:, None].astype(jnp.int32)
 
 
 if __name__ == "__main__":

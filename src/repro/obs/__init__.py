@@ -5,8 +5,10 @@ Signal modules, dependency-free and threaded through the request
 lifecycle:
 
 * :mod:`repro.obs.trace` — structured spans (admission → coalesce →
-  negotiate → dispatch → placement) with parent/child links; byte-stable
-  JSONL and Chrome-trace/Perfetto exports.
+  placement → dispatch → negotiate) with parent/child links; byte-stable
+  JSONL and Chrome-trace/Perfetto exports; written into a JAX profiler
+  trace as ``repro.*`` host events, with the host spans (submit, drain,
+  launch, wait, sample, …), while the profiler collects.
 * :mod:`repro.obs.metrics` — counters / gauges / fixed-bucket
   histograms in one process-global registry; Prometheus text
   exposition and a JSON snapshot (``launch/serve.py --metrics``).
@@ -23,9 +25,14 @@ Analysis/action modules (§19) that turn those signals into answers:
 * :mod:`repro.obs.slo` — per-tenant SLOs with multi-window burn rates
   and the admission shed/deprioritise hook queue.submit consults.
 
-All instrumentation is near-zero when off: ``bench_hotpath`` gates the
-warm-dispatch overhead with tracing+metrics enabled at ≤ 3% vs
-disabled.
+Off (no tracer installed, no profiler collecting) a span costs one
+global read, one ``TraceAnnotation.is_enabled()`` and the call's
+keyword packing, a few hundred nanoseconds on a CPU; the chip
+benchmark's untraced runs carry it, so its parent-against-change check
+measures it on the chip. What tracing costs when on is read from traced
+runs of a cell on the parent and on the change with the same seeds
+(PERF.md). ``bench_hotpath``'s ≤ 3% gate (tracer installed against
+off) is a CPU, interpret-mode figure and says nothing of the chip.
 """
 from repro.obs.critical import (Blame, attribute, blame_report,
                                 critical_path, export_jsonl as
@@ -38,13 +45,15 @@ from repro.obs.metrics import (DEFAULT_BUCKETS, Counter, Gauge, Histogram,
 from repro.obs.slo import Slo, SloMonitor, SloShedder
 from repro.obs.tail import TailSampler
 from repro.obs.trace import (NULL_SPAN, Span, Tracer, VirtualClock,
-                             get_tracer, set_tracer, span, using_tracer)
+                             enabled, get_tracer, host_span, set_tracer,
+                             span, using_tracer)
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "REGISTRY",
     "DEFAULT_BUCKETS", "default_registry", "start_http_server",
     "Span", "Tracer", "VirtualClock", "NULL_SPAN",
-    "get_tracer", "set_tracer", "span", "using_tracer",
+    "enabled", "get_tracer", "host_span", "set_tracer", "span",
+    "using_tracer",
     "DriftCell", "DriftTracker", "watch_programs",
     "Blame", "attribute", "blame_report", "critical_path",
     "export_blame_jsonl", "format_report", "max_residual",

@@ -1,23 +1,63 @@
 """Structured spans over the request lifecycle (DESIGN.md §15).
 
-Span taxonomy (parent ← child)::
+Tree spans (parent ← child), recorded by an installed :class:`Tracer`::
 
-    request                     one submitted WorkItem, root
+    request                     one submitted WorkItem, root (Tracer only)
     ├── admission               arity validation + coalesce key
     ├── coalesce                batch formation (parented to the batch's
     │                           first member; attrs name the rest)
-    └── placement               one lane dispatch by the scheduler
+    ├── reconfig                a region load charged to a lane
+    └── placement               one lane batch run by the scheduler
         └── dispatch            Program.__call__ / call_batch
             ├── negotiate       geometry sweep on memo miss
             │                   (outcome: disk_hit | sweep)
             ├── pallas_build    cold jit build of the pallas_call
             └── part            one Plan part (graph plans only)
 
-Tracing is **opt-in and near-zero when off**: the module global
-:data:`ACTIVE` is ``None`` by default and every instrumentation site
-collapses to one global read; :func:`span` returns the singleton
-:data:`NULL_SPAN` no-op context manager.  ``bench_hotpath`` gates the
-warm-dispatch overhead with a live tracer at ≤ 3%.
+Host spans, the host time the device may wait on (profiler only),
+each read by a per-layer metric of the chip benchmark
+(``chipbench/layer_metrics/``)::
+
+    submit                      RequestQueue.submit, the whole call
+    drain                       Scheduler.drain, the whole call
+    └── placement ─┬─ launch    the host issuing one lane batch's work
+                   └─ wait      jax.block_until_ready on its outputs
+    sample                      serve.sample, the per-step token pick
+
+``sched_idle_ms.prog`` reads the device idle inside ``submit`` and
+``drain`` but outside ``launch`` and ``wait`` (``sched_host_ms.prog``
+the host time there); ``launch_idle_ms.prog`` the device idle inside
+``launch``; ``sample_idle_ms.decode`` inside ``sample``.
+
+Three modes, decided per span by one gate (:func:`span`,
+:func:`host_span`):
+
+* **Off** — no :class:`Tracer` installed and the JAX profiler not
+  collecting.  The gate reads the :data:`ACTIVE` global and
+  ``jax.profiler.TraceAnnotation.is_enabled()`` and returns the
+  singleton :data:`NULL_SPAN`: no :class:`Span`, no annotation.  What
+  remains is the call and its keyword packing, a few hundred
+  nanoseconds a span on a CPU; ``tests/test_obs.py`` times it, and the
+  chip benchmark's untraced runs (``chipbench/run.py --trace 0``)
+  carry it in every end-to-end number.
+* **Profiler collecting** (``jax.profiler.trace`` / ``start_trace``,
+  e.g. ``chipbench/run.py --trace 1``), no Tracer — each span is a
+  ``jax.profiler.TraceAnnotation`` named ``repro.<span>``, with its
+  scalar attrs (``seq``, ``lane``, ``name``, …) as event metadata.  It
+  lands on the host line of the same ``.xplane.pb`` as the device ops,
+  on the same clock, so device idle can be put down to the span the
+  host was in.  Nothing else runs: no blame stamps, no ``under``, no
+  root listeners.  What this mode costs is read by running a traced
+  cell on the parent and on the change with the same seeds and
+  comparing the window's requests or steps and its idle share.
+* **Tracer installed** (``serve.py --obs-trace/--obs-tail``, the
+  analysis tier of §19) — tree spans are recorded exactly as without a
+  profiler, on the tracer's clock; when the profiler also collects they
+  are mirrored into it.  Host spans never enter the Tracer: several
+  would be roots there (``submit``, ``drain``, ``sample``) and change
+  its sampling, tail decisions and blame.  The request root is opened
+  in ``submit`` and finished by the scheduler, so it cannot nest on
+  the host line and stays in the Tracer only.
 
 Determinism: a :class:`Tracer` built on :class:`VirtualClock` assigns
 sequential span ids and synthetic timestamps, so
@@ -31,6 +71,13 @@ from __future__ import annotations
 import json
 import time
 from typing import Any, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation as _Annotation
+
+#: Prefix of every span name written into a profiler trace.
+PROFILER_PREFIX = "repro."
+#: ``True`` while a JAX profiler session collects (one C++ flag read).
+_collecting = _Annotation.is_enabled
 
 
 class Span:
@@ -79,27 +126,58 @@ class VirtualClock:
         return t
 
 
+def _metadata(attrs: Dict[str, Any]) -> Dict[str, Any]:
+    """The attrs a profiler event carries: scalars only (lists, keys and
+    arrays stay in the Tracer)."""
+    return {k: v for k, v in attrs.items()
+            if isinstance(v, (str, int, float, bool))}
+
+
 class _SpanCtx:
     """Context manager for one span: pushes onto the tracer's stack so
-    nested instrumentation sites parent correctly."""
+    nested instrumentation sites parent correctly, and writes the span
+    into the profiler as well when ``mirror`` is set."""
 
-    __slots__ = ("_tracer", "_span")
+    __slots__ = ("_tracer", "_span", "_ann")
 
-    def __init__(self, tracer: "Tracer", span: Span):
+    def __init__(self, tracer: "Tracer", span: Span, mirror: bool = False):
         self._tracer = tracer
         self._span = span
+        self._ann = _ProfilerSpan(span.name, span.attrs) if mirror else None
 
     def __enter__(self) -> Span:
         self._tracer._stack.append(self._span)
+        if self._ann is not None:
+            self._ann.__enter__()
         return self._span
 
     def __exit__(self, exc_type, exc, tb):
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         st = self._tracer._stack
         if st and st[-1] is self._span:
             st.pop()
         if exc_type is not None:
             self._span.attrs.setdefault("error", exc_type.__name__)
         self._tracer.finish(self._span)
+        return False
+
+
+class _ProfilerSpan:
+    """A span written only into the profiler trace.  Enters to ``None``
+    like :data:`NULL_SPAN`, so sites skip their attribute writes."""
+
+    __slots__ = ("_ann",)
+
+    def __init__(self, name: str, attrs: Dict[str, Any]):
+        self._ann = _Annotation(PROFILER_PREFIX + name, **_metadata(attrs))
+
+    def __enter__(self):
+        self._ann.__enter__()
+        return None
+
+    def __exit__(self, exc_type, exc, tb):
+        self._ann.__exit__(exc_type, exc, tb)
         return False
 
 
@@ -295,76 +373,6 @@ class Tracer:
         return json.dumps({"traceEvents": events,
                            "displayTimeUnit": "ms"}, sort_keys=True)
 
-    def export_otlp_json(self, service_name: str = "repro",
-                         scope_name: str = "repro.obs") -> str:
-        """OTLP/JSON (OpenTelemetry ``ExportTraceServiceRequest`` shape):
-        one resourceSpans → scopeSpans → spans list, ready to POST to an
-        OTLP/HTTP collector's ``/v1/traces`` or load into any OTel
-        tooling.
-
-        The span model maps directly: each root span starts a *trace*,
-        so every span's ``traceId`` is its root ancestor's id (zero-pad
-        hex, 16 bytes), ``spanId``/``parentSpanId`` are the internal
-        sequential ids (8 bytes), timestamps become unix-epoch
-        nanosecond strings (the clock's zero is the epoch — wall spans
-        are relative to process start, virtual spans to t=0), and attrs
-        become typed OTLP attribute values.  Byte-stable under a
-        :class:`VirtualClock`, like the other exports.
-        """
-        roots: Dict[int, int] = {}
-        by_id = {s.span_id: s for s in self.spans}
-        for s in sorted(self.spans, key=lambda s: s.span_id):
-            p = by_id.get(s.parent_id) if s.parent_id is not None else None
-            roots[s.span_id] = (roots[p.span_id] if p is not None
-                                else s.span_id)
-        out = []
-        for s in sorted(self.spans, key=lambda s: s.span_id):
-            end = s.end if s.end is not None else s.start
-            attrs = [{"key": k, "value": _otlp_value(v)}
-                     for k, v in sorted(s.attrs.items())]
-            out.append({
-                "traceId": f"{roots[s.span_id]:032x}",
-                "spanId": f"{s.span_id:016x}",
-                "parentSpanId": ("" if s.parent_id is None
-                                 else f"{s.parent_id:016x}"),
-                "name": s.name,
-                "kind": 1,  # SPAN_KIND_INTERNAL
-                "startTimeUnixNano": str(int(round(s.start * 1e9))),
-                "endTimeUnixNano": str(int(round(end * 1e9))),
-                "attributes": attrs,
-            })
-        doc = {"resourceSpans": [{
-            "resource": {"attributes": [{
-                "key": "service.name",
-                "value": {"stringValue": service_name},
-            }]},
-            "scopeSpans": [{
-                "scope": {"name": scope_name},
-                "spans": out,
-            }],
-        }]}
-        return json.dumps(doc, sort_keys=True, separators=(",", ":"))
-
-
-def _otlp_value(v) -> dict:
-    """One attr as an OTLP ``AnyValue``: typed when the type maps
-    (bool/int must be tested in that order — bool is an int subclass),
-    everything else through :func:`_chromable` then stringified."""
-    if isinstance(v, bool):
-        return {"boolValue": v}
-    if isinstance(v, int):
-        return {"intValue": str(v)}  # OTLP int64s ride as strings
-    if isinstance(v, float):
-        return {"doubleValue": v}
-    if isinstance(v, str):
-        return {"stringValue": v}
-    if isinstance(v, (list, tuple)):
-        return {"arrayValue": {"values": [_otlp_value(x) for x in v]}}
-    c = _chromable(v)
-    if type(c) is not type(v):
-        return _otlp_value(c)
-    return {"stringValue": repr(v)}  # pragma: no cover - defensive
-
 
 def _chromable(v):
     """Attrs down to JSON scalars: numpy 0-d values unwrap, anything
@@ -423,11 +431,32 @@ def using_tracer(tracer: Optional[Tracer]) -> _UsingTracer:
     return _UsingTracer(tracer)
 
 
-def span(name: str, parent=_CURRENT, **attrs):
-    """Module-level helper: a span on the active tracer, or
-    :data:`NULL_SPAN` when tracing is off.  The no-op path costs one
-    global read plus kwargs packing."""
+def enabled() -> bool:
+    """Whether a span opened now would be recorded anywhere: a Tracer is
+    installed or the profiler collects.  Sites whose attrs are costly to
+    build test this first."""
+    return ACTIVE is not None or _collecting()
+
+
+def span(name: str, /, parent=_CURRENT, **attrs):
+    """The gate for a tree span (module docstring): on the active tracer
+    (mirrored into the profiler while it collects), a profiler event
+    alone when no tracer is installed, else :data:`NULL_SPAN`.  Enters
+    to the :class:`Span` only when a tracer records it, so sites guard
+    attribute writes with ``if sp is not None``."""
     tr = ACTIVE
     if tr is None:
+        if not _collecting():
+            return NULL_SPAN
+        return _ProfilerSpan(name, attrs)
+    return _SpanCtx(tr, tr.start_span(name, parent=parent, **attrs),
+                    _collecting())
+
+
+def host_span(name: str, /, **attrs):
+    """The gate for a host span (module docstring): a profiler event
+    while the profiler collects, else :data:`NULL_SPAN`.  A Tracer never
+    records it.  Enters to ``None``."""
+    if not _collecting():
         return NULL_SPAN
-    return tr.span(name, parent=parent, **attrs)
+    return _ProfilerSpan(name, attrs)

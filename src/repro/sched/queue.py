@@ -258,51 +258,51 @@ class RequestQueue:
         operand list. ``arrival``/``deadline`` are runtime-clock seconds
         (the scheduler's virtual clock, or seconds since its wall epoch).
         """
-        self._admit(target, operands)
-        if weight <= 0:
-            raise ValueError(f"weight must be positive, got {weight}")
-        seq = next(self._seq)
-        weight = float(weight)
-        verdict = ("accept" if self.admission is None
-                   else self.admission.admit(tenant=tenant,
-                                             now=float(arrival)))
-        tr = _trace.ACTIVE
-        root = None
-        if tr is not None:
-            root = tr.start_span("request", parent=None, seq=seq,
-                                 tenant=tenant, arrival=float(arrival),
-                                 deadline=deadline)
-        if verdict == "shed":
-            # rejected before queueing: the root span is finished
-            # immediately (no blame inputs, so critical.attribute skips
-            # it) and the item never becomes pending
-            _shed_total(tenant).inc()
-            if tr is not None and root is not None:
-                tr.finish(root, shed=True)
-            return WorkItem(seq=seq, target=target,
+        with _trace.host_span("submit", tenant=tenant):
+            self._admit(target, operands)
+            if weight <= 0:
+                raise ValueError(f"weight must be positive, got {weight}")
+            seq = next(self._seq)
+            weight = float(weight)
+            verdict = ("accept" if self.admission is None
+                       else self.admission.admit(tenant=tenant,
+                                                 now=float(arrival)))
+            tr = _trace.ACTIVE
+            root = None
+            if tr is not None:
+                root = tr.start_span("request", parent=None, seq=seq,
+                                     tenant=tenant, arrival=float(arrival),
+                                     deadline=deadline)
+            if verdict == "shed":
+                # rejected before queueing: the root span is finished
+                # immediately (no blame inputs, so critical.attribute
+                # skips it) and the item never becomes pending
+                _shed_total(tenant).inc()
+                if tr is not None and root is not None:
+                    tr.finish(root, shed=True)
+                return WorkItem(seq=seq, target=target,
+                                operands=tuple(operands), deadline=deadline,
+                                arrival=float(arrival), tenant=tenant,
+                                weight=weight, mode=mode, cost_key=cost_key,
+                                key=None, span=root, shed=True)
+            if verdict == "deprioritise":
+                _deprioritised_total(tenant).inc()
+                weight *= getattr(self.admission, "weight_factor", 0.25)
+                if root is not None:
+                    root.attrs["deprioritised"] = True
+            with _trace.span("admission", parent=root, seq=seq) as adm:
+                key = coalesce_key(target, operands)
+                if adm is not None:
+                    adm.attrs["coalesce_key"] = (None if key is None
+                                                 else repr(key))
+            item = WorkItem(seq=seq, target=target,
                             operands=tuple(operands), deadline=deadline,
                             arrival=float(arrival), tenant=tenant,
                             weight=weight, mode=mode, cost_key=cost_key,
-                            key=None, span=root, shed=True)
-        if verdict == "deprioritise":
-            _deprioritised_total(tenant).inc()
-            weight *= getattr(self.admission, "weight_factor", 0.25)
-            if root is not None:
-                root.attrs["deprioritised"] = True
-        with (_trace.NULL_SPAN if tr is None
-              else tr.span("admission", parent=root, seq=seq)) as adm:
-            key = coalesce_key(target, operands)
-            if adm is not None:
-                adm.attrs["coalesce_key"] = (None if key is None
-                                             else repr(key))
-        item = WorkItem(seq=seq, target=target,
-                        operands=tuple(operands), deadline=deadline,
-                        arrival=float(arrival), tenant=tenant,
-                        weight=weight, mode=mode, cost_key=cost_key,
-                        key=key, span=root)
-        self.pending.append(item)
-        _SUBMITS.inc()
-        return item
+                            key=key, span=root)
+            self.pending.append(item)
+            _SUBMITS.inc()
+            return item
 
     def next_arrival(self, after: float) -> Optional[float]:
         """Earliest pending arrival strictly later than ``after``."""
@@ -332,12 +332,11 @@ class RequestQueue:
                 groups[gk] = b
                 order.append(b)
             b.items.append(it)
-        tr = _trace.ACTIVE
-        if tr is not None:
+        if _trace.enabled():
             for b in order:
-                with tr.span("coalesce", parent=b.items[0].span,
-                             batch_seq=b.seq, n_items=len(b.items),
-                             coalesced=b.coalesced,
-                             members=[it.seq for it in b.items]):
+                with _trace.span("coalesce", parent=b.items[0].span,
+                                 batch_seq=b.seq, n_items=len(b.items),
+                                 coalesced=b.coalesced,
+                                 members=[it.seq for it in b.items]):
                     pass
         return order

@@ -53,11 +53,15 @@ from them with zero candidate sweeps and zero beam searches.
 Observability (DESIGN.md §15): each dispatched batch runs under a
 ``placement`` span parented to its first member's ``request`` root
 (so a served request yields ONE connected span tree: admission →
-coalesce → placement → dispatch → negotiate/pallas_build), root spans
-are finished at completion with predicted/observed seconds, and the
-registry carries per-tenant ``repro_sched_latency_seconds`` histograms
-(p50/p99 in the snapshot), ``repro_sched_queue_depth``, round/item
-counters, and ``repro_sched_deadline_miss_total``.
+coalesce → placement → dispatch → negotiate/pallas_build); while a
+profiler collects, ``drain``, ``launch`` and ``wait`` host spans put
+the device's idle time down to the part of the round the host was in
+(the chip benchmark's ``sched_idle_ms.prog`` and
+``launch_idle_ms.prog`` read them); root spans are finished at
+completion with predicted/observed seconds, and the registry carries
+per-tenant ``repro_sched_latency_seconds`` histograms (p50/p99 in the
+snapshot), ``repro_sched_queue_depth``, round/item counters, and
+``repro_sched_deadline_miss_total``.
 
 Blame attribution + SLOs (DESIGN.md §19): each root span's finish call
 also stamps the request's blame inputs — ``start``, ``solo_s``,
@@ -569,7 +573,6 @@ class Scheduler:
         n = len(round_batches)
         if self.regions is None:
             return list(range(n)), [0.0] * n
-        tr = _trace.ACTIVE
         lanes, charges = [], []
         free = list(range(self.n_lanes))
         for b in round_batches:
@@ -586,10 +589,10 @@ class Scheduler:
                         "region", op=ev.op, lane=ev.lane,
                         key=repr(ev.key), cost_s=ev.cost_s,
                         round=self._round)
-            if cost_s and tr is not None:
-                with tr.span("reconfig", parent=b.items[0].span,
-                             lane=lane, key=repr(rk), cost_s=cost_s,
-                             round=self._round):
+            if cost_s and _trace.enabled():
+                with _trace.span("reconfig", parent=b.items[0].span,
+                                 lane=lane, key=repr(rk), cost_s=cost_s,
+                                 round=self._round):
                     pass
         return lanes, charges
 
@@ -629,31 +632,28 @@ class Scheduler:
                 observed = [makespan] * len(round_batches)
                 finishes = [start + makespan] * len(round_batches)
             results = [[None] * len(b.items) for b in round_batches]
-            if tr is not None:
+            if _trace.enabled():
                 for lane, ch, b in zip(lanes, chans, round_batches):
                     extra = {"channel": ch} if channels is not None else {}
-                    with tr.span("placement", parent=b.items[0].span,
-                                 lane=lane, round=self._round,
-                                 batch_seq=b.seq, n_items=len(b.items),
-                                 virtual=True, **extra):
+                    with _trace.span("placement", parent=b.items[0].span,
+                                     lane=lane, round=self._round,
+                                     batch_seq=b.seq, n_items=len(b.items),
+                                     virtual=True, **extra):
                         pass
         else:
             observed, results, finishes = [], [], []
             done = 0.0
             for lane, b in zip(lanes, round_batches):
                 t0 = time.perf_counter()
-                if tr is not None and b.items[0].span is not None:
-                    # hang the lane's work off the request's root span so
-                    # the dispatch/negotiate children nest under it
-                    with tr.under(b.items[0].span), \
-                            tr.span("placement", lane=lane,
-                                    round=self._round, batch_seq=b.seq,
-                                    n_items=len(b.items)):
+                # a tracer hangs the lane's work off the request's root
+                # span, so the dispatch/negotiate children nest under it
+                with _trace.span("placement", parent=b.items[0].span,
+                                 lane=lane, round=self._round,
+                                 batch_seq=b.seq, n_items=len(b.items)):
+                    with _trace.host_span("launch"):
                         out = self._dispatch_batch(b)
+                    with _trace.host_span("wait"):
                         jax.block_until_ready(out)
-                else:
-                    out = self._dispatch_batch(b)
-                    jax.block_until_ready(out)
                 dt = time.perf_counter() - t0
                 done += dt
                 observed.append(dt)
@@ -756,34 +756,37 @@ class Scheduler:
         the round did not take re-enter the queue, so later arrivals
         compete under the policy instead of waiting out a long backlog.
         """
-        while self.queue:
-            now = self.now()
-            batches = self.queue.pop_ready(now)
-            if not batches:
-                nxt = self.queue.next_arrival(now)
-                if nxt is None:
-                    nxt = min(it.arrival for it in self.queue.pending)
-                if self.clock == "virtual":
-                    self._now = max(self._now, nxt)
-                else:
-                    time.sleep(max(0.0, nxt - now))
-                continue
-            self._record_submits(batches)
-            if self.regions is not None:
-                # feed the reuse predictor in arrival order, once per item
-                fresh = [(it, self._region_key(it)) for b in batches
-                         for it in b.items
-                         if it.seq not in self._region_noted]
-                for it, rk in sorted(fresh,
-                                     key=lambda p: (p[0].arrival,
-                                                    p[0].seq)):
-                    self._region_noted.add(it.seq)
-                    self.regions.note_arrival(rk, it.tenant, it.arrival)
-            ordered = self.policy.order(batches, self.now(), self._estimate)
-            self._run_round(ordered[:self.n_lanes])
-            for b in ordered[self.n_lanes:]:
-                self.queue.pending.extend(b.items)
-        return self.report()
+        with _trace.host_span("drain"):
+            while self.queue:
+                now = self.now()
+                batches = self.queue.pop_ready(now)
+                if not batches:
+                    nxt = self.queue.next_arrival(now)
+                    if nxt is None:
+                        nxt = min(it.arrival for it in self.queue.pending)
+                    if self.clock == "virtual":
+                        self._now = max(self._now, nxt)
+                    else:
+                        time.sleep(max(0.0, nxt - now))
+                    continue
+                self._record_submits(batches)
+                if self.regions is not None:
+                    # feed the reuse predictor in arrival order, once per
+                    # item
+                    fresh = [(it, self._region_key(it)) for b in batches
+                             for it in b.items
+                             if it.seq not in self._region_noted]
+                    for it, rk in sorted(fresh,
+                                         key=lambda p: (p[0].arrival,
+                                                        p[0].seq)):
+                        self._region_noted.add(it.seq)
+                        self.regions.note_arrival(rk, it.tenant, it.arrival)
+                ordered = self.policy.order(batches, self.now(),
+                                            self._estimate)
+                self._run_round(ordered[:self.n_lanes])
+                for b in ordered[self.n_lanes:]:
+                    self.queue.pending.extend(b.items)
+            return self.report()
 
     def report(self) -> Report:
         missed = sorted(

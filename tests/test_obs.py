@@ -725,68 +725,6 @@ class TestSampling:
                 obs_trace.Tracer(sample_rate=bad)
 
 
-class TestOtlpExport:
-    """OTLP/JSON export (ISSUE 8): collector-shaped document, byte-
-    stable under the virtual clock."""
-
-    def _tree(self):
-        t = obs_trace.Tracer(clock=obs_trace.VirtualClock())
-        root = t.start_span("request", parent=None, tenant="a", seq=1)
-        child = t.start_span("admission", parent=root, ok=True)
-        t.finish(child)
-        t.finish(root)
-        lone = t.start_span("gc", parent=None, freed=3.5)
-        t.finish(lone)
-        return t
-
-    def test_document_shape(self):
-        doc = json.loads(self._tree().export_otlp_json())
-        rs, = doc["resourceSpans"]
-        svc = rs["resource"]["attributes"][0]
-        assert svc["key"] == "service.name"
-        assert svc["value"] == {"stringValue": "repro"}
-        ss, = rs["scopeSpans"]
-        assert ss["scope"]["name"] == "repro.obs"
-        assert len(ss["spans"]) == 3
-
-    def test_trace_and_parent_ids(self):
-        doc = json.loads(self._tree().export_otlp_json())
-        spans = {s["name"]: s
-                 for s in doc["resourceSpans"][0]["scopeSpans"][0]["spans"]}
-        root, child = spans["request"], spans["admission"]
-        assert child["traceId"] == root["traceId"]  # same request tree
-        assert spans["gc"]["traceId"] != root["traceId"]
-        assert child["parentSpanId"] == root["spanId"]
-        assert root["parentSpanId"] == ""
-        assert len(root["traceId"]) == 32 and len(root["spanId"]) == 16
-        assert all(s["kind"] == 1 for s in spans.values())
-
-    def test_nanos_are_strings(self):
-        doc = json.loads(self._tree().export_otlp_json())
-        s = doc["resourceSpans"][0]["scopeSpans"][0]["spans"][0]
-        assert isinstance(s["startTimeUnixNano"], str)
-        assert int(s["endTimeUnixNano"]) >= int(s["startTimeUnixNano"])
-
-    def test_typed_attributes(self):
-        doc = json.loads(self._tree().export_otlp_json())
-        spans = {s["name"]: s
-                 for s in doc["resourceSpans"][0]["scopeSpans"][0]["spans"]}
-        attrs = {a["key"]: a["value"]
-                 for a in spans["request"]["attributes"]}
-        assert attrs["tenant"] == {"stringValue": "a"}
-        assert attrs["seq"] == {"intValue": "1"}
-        ok = {a["key"]: a["value"]
-              for a in spans["admission"]["attributes"]}["ok"]
-        assert ok == {"boolValue": True}
-        freed = {a["key"]: a["value"]
-                 for a in spans["gc"]["attributes"]}["freed"]
-        assert freed == {"doubleValue": 3.5}
-
-    def test_byte_stable(self):
-        assert (self._tree().export_otlp_json()
-                == self._tree().export_otlp_json())
-
-
 class TestDriftThreshold:
     """Threshold wiring (ISSUE 8): chronic drift is queryable via
     exceeding() and counted in repro_drift_exceeded_total."""
@@ -1193,34 +1131,158 @@ class TestSloShedder:
 
 
 # ---------------------------------------------------------------------------
-# §19: OTLP round-trip of the scheduler's blame/SLO span attributes
+# §19: the scheduler's blame/SLO span attributes, read back
 # ---------------------------------------------------------------------------
 
 class TestOtlpBlameAttrs:
+    """The blame/SLO attrs round-trip through :meth:`Tracer.export_jsonl`
+    (what ``serve.py --obs-tail`` writes): typed floats and ints, and
+    integer span/parent ids stable across identical runs.  The class and
+    its id test keep the names they had when they read the attrs through
+    the OTLP exporter, since removed."""
+
     def _run_doc(self):
         t = obs_trace.Tracer(clock=obs_trace.VirtualClock())
         with obs_trace.using_tracer(t):
             _blame_run(n=3)
-        return t.export_otlp_json()
+        return t.export_jsonl()
 
     def test_blame_inputs_typed(self):
-        doc = json.loads(self._run_doc())
-        spans = doc["resourceSpans"][0]["scopeSpans"][0]["spans"]
+        spans = [json.loads(line) for line in self._run_doc().splitlines()]
         reqs = [s for s in spans if s["name"] == "request"]
         assert len(reqs) == 3
         for s in reqs:
-            attrs = {a["key"]: a["value"] for a in s["attributes"]}
+            attrs = s["attrs"]
             for k in ("solo_s", "batch_s", "swap_s", "contention_s",
                       "dram_busy_s", "channel_busy_s"):
-                assert "doubleValue" in attrs[k], (k, attrs[k])
-            assert attrs["clock"] == {"stringValue": "virtual"}
-            assert attrs["channel"] == {"intValue": "0"}
-            assert "intValue" in attrs["lane"]
+                assert type(attrs[k]) is float, (k, attrs[k])
+            assert attrs["clock"] == "virtual"
+            assert type(attrs["channel"]) is int and attrs["channel"] == 0
+            assert type(attrs["lane"]) is int
 
     def test_hex_ids_stable_across_identical_runs(self):
         self._run_doc()                  # warm geometry/dispatch state
         a, b = self._run_doc(), self._run_doc()
-        assert a == b                    # traceId/spanId hex included
-        s = json.loads(a)["resourceSpans"][0]["scopeSpans"][0]["spans"][0]
-        assert re.fullmatch(r"[0-9a-f]{32}", s["traceId"])
-        assert re.fullmatch(r"[0-9a-f]{16}", s["spanId"])
+        assert a == b                    # span and parent ids included
+        first = json.loads(a.splitlines()[0])
+        assert type(first["span_id"]) is int and first["span_id"] == 1
+        assert first["parent_id"] is None
+
+
+# ---------------------------------------------------------------------------
+# Spans on the profiler's clock: the three modes of the gate
+# ---------------------------------------------------------------------------
+
+def _profiled(fn, log_dir):
+    """Run ``fn`` inside a ``cb.request`` annotation under a JAX profiler
+    session; returns the host events named ``repro.*`` or ``cb.*`` as
+    ``(name, start_ns, end_ns, stats)``, by start."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    with jax.profiler.trace(str(log_dir), profiler_options=opts):
+        with jax.profiler.TraceAnnotation("cb.request"):
+            fn()
+    (path,) = glob.glob(os.path.join(str(log_dir), "**", "*.xplane.pb"),
+                        recursive=True)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith(("repro.", "cb.")):
+                    out.append((e.name, e.start_ns, e.end_ns,
+                                dict(e.stats)))
+    return sorted(out, key=lambda e: (e[1], -e[2]))
+
+
+def _request_and_sample():
+    """One scheduled request (the prefix sum), then one greedy token
+    sample."""
+    from repro.kernels import ops
+    from repro.launch import serve
+    q = RequestQueue()
+    q.submit(ops.prefix_sum, (jnp.arange(1024, dtype=F32),),
+             tenant="t0", arrival=0.0)
+    Scheduler(q, policy="fifo", n_lanes=1, clock="wall").drain()
+    serve.sample(jnp.ones((2, 16), F32), None, 0.0)
+
+
+def _inside(inner, outer):
+    return outer[1] <= inner[1] and inner[2] <= outer[2]
+
+
+class TestProfilerSpans:
+    def test_off_builds_no_span_and_no_annotation(self, monkeypatch):
+        def boom(*a, **k):
+            raise AssertionError("built while off")
+        monkeypatch.setattr(obs_trace, "_Annotation", boom)
+        monkeypatch.setattr(obs_trace, "Span", boom)
+        assert not obs_trace.enabled()
+        assert obs_trace.span("placement", lane=0) is obs_trace.NULL_SPAN
+        assert obs_trace.host_span("launch") is obs_trace.NULL_SPAN
+        _request_and_sample()        # every site runs through the gate
+
+    def test_off_gate_is_cheap(self):
+        import timeit
+        n = 20000
+        per_call = min(timeit.repeat(
+            lambda: obs_trace.span("dispatch", n_items=1), number=n,
+            repeat=3)) / n
+        assert per_call < 10e-6
+
+    def test_profiler_alone_writes_nested_program_spans(self, tmp_path):
+        assert obs_trace.get_tracer() is None
+        ev = _profiled(_request_and_sample, tmp_path)
+        names = [e[0] for e in ev]
+        for want in ("submit", "admission", "drain", "placement",
+                     "launch", "wait", "sample"):
+            assert "repro." + want in names, (want, names)
+        assert "repro.request" not in names      # the root cannot nest
+        by = {}
+        for e in ev:
+            by.setdefault(e[0], []).append(e)
+        (cb,) = by["cb.request"]
+        assert all(_inside(e, cb) for e in ev)   # one clock for both
+        (submit,), (drain,) = by["repro.submit"], by["repro.drain"]
+        assert _inside(by["repro.admission"][0], submit)
+        assert submit[2] <= drain[1]
+        (place,) = by["repro.placement"]
+        assert _inside(place, drain)
+        assert place[3]["lane"] == 0
+        (launch,), (wait,) = by["repro.launch"], by["repro.wait"]
+        assert _inside(launch, place) and _inside(wait, place)
+        assert launch[2] <= wait[1]
+        assert by["repro.admission"][0][3]["seq"] >= 0
+        (sample,) = by["repro.sample"]
+        assert drain[2] <= sample[1]
+        # spans on one thread nest properly: no two overlap partially
+        for i, a in enumerate(ev):
+            for b in ev[i + 1:]:
+                assert b[1] >= a[2] or _inside(b, a), (a, b)
+
+    def test_tracer_and_profiler_both_get_the_spans(self, tmp_path,
+                                                    tracer):
+        ev = _profiled(_request_and_sample, tmp_path)
+        names = {e[0] for e in ev}
+        for want in ("admission", "coalesce", "placement"):
+            assert tracer.named(want), want
+            assert "repro." + want in names, want
+        assert "repro.launch" in names and "repro.sample" in names
+        # the tracer records as without a profiler: one connected tree,
+        # and no host spans
+        (root,) = [s for s in tracer.spans if s.parent_id is None]
+        assert root.name == "request" and root.attrs["observed_s"] > 0
+        assert len(tracer.subtree_names(root)) == len(tracer.spans)
+        assert not {"submit", "drain", "launch", "sample"} & {
+            s.name for s in tracer.spans}
+
+    def test_tracer_without_profiler_opens_no_annotation(self, tracer,
+                                                          monkeypatch):
+        def boom(*a, **k):
+            raise AssertionError("annotation opened with no profiler")
+        monkeypatch.setattr(obs_trace, "_Annotation", boom)
+        _request_and_sample()
+        assert tracer.named("placement") and tracer.named("admission")
