@@ -181,9 +181,9 @@ def phase_stream(n: int, mode: str, seed: int = 0) -> dict:
 
 def phase_sort(n: int, mode: str, seed: int = 0, merge_width: int = 2048
                ) -> dict:
-    """§4.3.1: c2_sort and c1_merge alone, then the sortnet mergesort.
-    The mergesort's widest levels run on the base core (XLA sort), so
-    the two instructions are also checked on their own, exactly."""
+    """§4.3.1: c2_sort and c1_merge alone, then the sortnet mergesort,
+    whose levels wider than one kernel block run as merge-path windows
+    on c1_merge; each is checked exactly."""
     x = jax.jit(lambda k: jax.random.randint(
         k, (n,), jnp.iinfo(jnp.int32).min, jnp.iinfo(jnp.int32).max,
         jnp.int32))(jax.random.PRNGKey(seed))

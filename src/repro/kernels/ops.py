@@ -26,6 +26,7 @@ from repro.core.stream import LANES, StreamConfig, flatten_to_blocks
 # duplicated here and in stream_copy.py; see core/stream.py).
 from repro.core.stream import as_rows as _as_rows
 from repro.core.stream import pad_rows as _pad_rows
+from repro.obs import metrics as _metrics
 
 from . import flashattn as _fa
 from . import prefix_scan as _ps
@@ -77,7 +78,16 @@ def sort_chunks(x, width: int = 8, descending: bool = False, mode=None):
 # c1_merge  (2 vector in, 2 vector out — the full I'-type operand budget)
 # ---------------------------------------------------------------------------
 
-def _merge_kernel(a, b, width=None, *, interpret: bool = False):
+def _merge_kernel(a, b, width=None, windows=None, *, interpret: bool = False):
+    if windows is not None:
+        if width % LANES == 0 and a.dtype.itemsize == b.dtype.itemsize == 4:
+            return _sn.merge_windows_pallas(a, None if b is a else b,
+                                            *windows, width=width,
+                                            interpret=interpret)
+        # narrower windows, or other key widths: gather, then merge rows
+        a_start, a_stop, b_start, b_stop = windows
+        a = ref.window_keys(a, a_start, a_stop, width)
+        b = ref.window_keys(b, b_start, b_stop, width)
     w = width or a.shape[-1]
     if a.shape != b.shape or a.shape[-1] % w:
         raise ValueError(f"operands {a.shape}, {b.shape} must match and "
@@ -101,8 +111,11 @@ isa.register(Instruction(
 ))
 
 
-def merge_sorted(a, b, width=None, mode=None):
-    return isa.call("c1_merge", a, b, width=width, mode=mode)
+def merge_sorted(a, b, width=None, mode=None, windows=None):
+    """``windows=(a_start, a_stop, b_start, b_stop)`` loads each register
+    pair from its own offsets (``ref.merge_sorted``)."""
+    return isa.call("c1_merge", a, b, width=width, windows=windows,
+                    mode=mode)
 
 
 # ---------------------------------------------------------------------------
@@ -372,13 +385,63 @@ def c0_pipeline_graph(kind: str = "axpby_residual"):
 # The mergesort application (paper §4.3.1): sort-in-chunks + pairwise merges.
 # ---------------------------------------------------------------------------
 
+_MERGE_PATH_LEVELS = _metrics.REGISTRY.counter(
+    "repro_mergesort_mergepath_levels_total",
+    help="mergesort levels wider than one kernel block, run as merge-path "
+         "partitioned c1_merge launches")
+
+
+@functools.partial(jax.jit, static_argnames="block")
+def _merge_path(x, w, *, block: int):
+    """Merge-path partition of one merge level into ``block``-key windows.
+
+    ``x`` holds pairs of sorted runs ``a, b``, each ``w`` keys long, back to
+    back (any shape; ``w`` is traced, so every level shares one compiled
+    function). Output block ``k`` of the level, at diagonal ``d`` of its
+    pair, starts after the first ``i`` keys of ``a`` and ``j = d - i`` of
+    ``b``, where the co-rank ``i`` counts the keys of ``a`` among the first
+    ``d`` of merge(a, b) (ties taken from ``a`` first): a binary search
+    over all blocks at once. Returns the c1_merge ``windows`` of the level:
+    ``a`` from ``i`` and ``b`` from ``j``, each cut at its run's end. The
+    ``block`` smallest keys of window pair ``k`` are output block ``k``:
+    every key of the pair outside it ranks after it, and the padding past
+    a run's end or a tie only puts an equal key in its place.
+    """
+    flat = x.reshape(-1)
+    start = jnp.arange(flat.size // block, dtype=jnp.int32) * block
+    base = start - start % (2 * w)          # the pair's first key
+    d = start - base                        # the block's diagonal in its pair
+    lo, hi = jnp.maximum(d - w, 0), jnp.minimum(d, w)
+
+    def search(_, bounds):
+        lo, hi = bounds
+        mid = (lo + hi) // 2
+        a_mid = flat[base + jnp.minimum(mid, w - 1)]
+        b_before = flat[base + w + jnp.maximum(d - mid - 1, 0)]
+        after = a_mid <= b_before           # a[mid] is among the first d
+        open_ = lo < hi
+        return (jnp.where(open_ & after, mid + 1, lo),
+                jnp.where(open_ & ~after, mid, hi))
+
+    # hi - lo <= w halves each step: w.bit_length() steps close it
+    steps = 32 - jax.lax.clz(jnp.int32(w))
+    i, _ = jax.lax.fori_loop(0, steps, search, (lo, hi))
+    return base + i, base + w, base + w + d - i, base + 2 * w
+
+
 def sortnet_mergesort(x: jax.Array, base_width: int = 8,
                       max_kernel_width: int = 4096, mode=None) -> jax.Array:
     """Sort the last axis using c2_sort for chunks then c1_merge levels.
 
-    Above ``max_kernel_width`` (VMEM working-set bound, the same limit the
-    paper hits when a merge no longer fits one register pair) the remaining
-    merge levels run on the base core (XLA sort over pairs).
+    Levels up to ``max_kernel_width`` keys wide (the VMEM working-set
+    bound, the same limit the paper hits when a merge no longer fits one
+    register pair) merge whole run pairs in one c1_merge launch. Each wider
+    level is merge-path partitioned (:func:`_merge_path`) into windows of
+    ``block`` = ``max_kernel_width // 2`` keys, rounded down to a power of
+    two, and runs as one c1_merge launch that loads every window pair from
+    its offsets and keeps the lower halves; counted by
+    ``repro_mergesort_mergepath_levels_total``. The work per level stays
+    linear, and every wide level launches the same kernel shape.
     """
     n = x.shape[-1]
     if n & (n - 1):
@@ -388,18 +451,26 @@ def sortnet_mergesort(x: jax.Array, base_width: int = 8,
     x = sort_chunks(x, width=base_width, mode=mode)
     w = base_width
     lead = x.shape[:-1]
-    while w < n:
+    while w < n and 2 * w <= max_kernel_width:
         pairs = x.reshape(*lead, n // (2 * w), 2, w)
-        a = pairs[..., 0, :]
-        b = pairs[..., 1, :]
-        if 2 * w <= max_kernel_width:
-            lo, hi = merge_sorted(a.reshape(-1, w), b.reshape(-1, w),
-                                  width=w, mode=mode)
-            merged = jnp.concatenate(
-                [lo.reshape(*lead, n // (2 * w), w),
-                 hi.reshape(*lead, n // (2 * w), w)], axis=-1)
-        else:  # base-core fallback for huge merge levels
-            merged = jnp.sort(jnp.concatenate([a, b], axis=-1), axis=-1)
+        lo, hi = merge_sorted(pairs[..., 0, :].reshape(-1, w),
+                              pairs[..., 1, :].reshape(-1, w),
+                              width=w, mode=mode)
+        merged = jnp.concatenate(
+            [lo.reshape(*lead, n // (2 * w), w),
+             hi.reshape(*lead, n // (2 * w), w)], axis=-1)
         x = merged.reshape(*lead, n)
         w *= 2
-    return x
+    if w == n:
+        return x
+    if max_kernel_width < 4:
+        raise ValueError(f"max_kernel_width {max_kernel_width} < 4 leaves "
+                         f"no merge block of two or more keys")
+    block = 1 << (max_kernel_width.bit_length() - 2)
+    x = x.reshape(-1, block)        # one shape at every level: one trace
+    while w < n:
+        x, _ = merge_sorted(x, x, width=block, mode=mode,
+                            windows=_merge_path(x, w, block=block))
+        _MERGE_PATH_LEVELS.inc()
+        w *= 2
+    return x.reshape(*lead, n)

@@ -25,14 +25,42 @@ def sort_chunks(x: jax.Array, width: int = 8, descending: bool = False) -> jax.A
     return s.reshape(shp)
 
 
-def merge_sorted(a: jax.Array, b: jax.Array,
-                 width: int | None = None) -> tuple[jax.Array, jax.Array]:
+def max_key(dtype):
+    """The key no other key sorts after: padding for merge windows."""
+    if jnp.issubdtype(dtype, jnp.floating):
+        return jnp.inf
+    return jnp.iinfo(dtype).max
+
+
+def window_keys(x: jax.Array, start: jax.Array, stop: jax.Array,
+                width: int) -> jax.Array:
+    """(windows, width) keys: row k is ``x.ravel()[start[k]:][:width]``,
+    each key at flat index ``stop[k]`` or later read as :func:`max_key`."""
+    flat = x.reshape(-1)
+    idx = start[:, None] + jnp.arange(width, dtype=start.dtype)
+    fill = max_key(flat.dtype)
+    keys = flat.at[idx].get(mode="fill", fill_value=fill)
+    return jnp.where(idx < stop[:, None], keys, fill)
+
+
+def merge_sorted(a: jax.Array, b: jax.Array, width: int | None = None,
+                 windows=None) -> tuple[jax.Array, jax.Array]:
     """Merge two sorted vectors (paper c1_merge): returns (lower, upper).
 
     a, b: (..., n), each `width`-chunk sorted ascending (width=None → whole
     row). Per chunk, output the lower/upper halves of the sorted 2w-element
     union (written back to v1/v2 in the paper).
+
+    With ``windows = (a_start, a_stop, b_start, b_stop)`` (int32, one entry
+    per window) the chunks are loaded from anywhere in ``a`` and ``b``:
+    chunk k is :func:`window_keys` of ``a`` from ``a_start[k]`` up to
+    ``a_stop[k]`` (of ``b`` likewise), and both outputs are (windows,
+    width).
     """
+    if windows is not None:
+        a_start, a_stop, b_start, b_stop = windows
+        a = window_keys(a, a_start, a_stop, width)
+        b = window_keys(b, b_start, b_stop, width)
     n = a.shape[-1]
     w = width or n
     ar = a.reshape(*a.shape[:-1], n // w, w)

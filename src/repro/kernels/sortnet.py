@@ -14,6 +14,8 @@ and a select — the whole network fuses into ONE kernel (one "instruction"),
 versus the ~13-instruction min/max/shuffle sequences of fixed SIMD ISAs
 the paper counts in §6. Rows stream through the grid back-to-back, the
 pipelining the paper gets from its `c1_cycles` shift registers.
+``merge_windows_pallas`` is ``c1_merge`` with each register pair loaded
+from its own key offsets (the mergesort's merge-path levels).
 """
 from __future__ import annotations
 
@@ -27,6 +29,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.stream import LANES
+
+from .ref import max_key
 
 
 def _check_pow2(w: int, what: str) -> None:
@@ -235,6 +239,119 @@ def merge_sorted_pallas(a: jax.Array, b: jax.Array, *, width: Optional[int] = No
         out_shape=(shp, shp),
         interpret=interpret,
     )(a, b)
+
+
+# ---------------------------------------------------------------------------
+# c1_merge over windows: each register pair loaded from its own key offset.
+# ---------------------------------------------------------------------------
+
+def _merge_windows_body(width: int, rows: int, span: int, fill,
+                        a_start, a_stop, b_start, b_stop, a_hbm, b_hbm,
+                        lo_ref, hi_ref, a_buf, b_buf, sem, stage):
+    """Grid step g merges windows g·rows … g·rows+rows-1. Each window is
+    one DMA of ``span`` = width + LANES keys from the lane tile that holds
+    its first key (moved back so it ends inside the array); the next
+    step's DMAs run while this step merges."""
+    step = pl.program_id(0)
+    slot = step % 2
+    size = a_hbm.shape[1]
+
+    def origin(start):
+        return jnp.minimum(start - start % LANES, size - span)
+
+    def copy(o, s, at, into):
+        hbm, buf = (a_hbm, a_buf) if o == 0 else (b_hbm, b_buf)
+        return pltpu.make_async_copy(hbm.at[:, pl.ds(at, span)],
+                                     buf.at[into, s], sem.at[o, into, s])
+
+    def fetch(g, into):
+        for o, starts in enumerate((a_start, b_start)):
+            for s in range(rows):
+                at = origin(starts[g * rows + s])
+                copy(o, s, pl.multiple_of(at, LANES), into).start()
+
+    @pl.when(step == 0)
+    def _():
+        fetch(step, slot)
+
+    @pl.when(step + 1 < pl.num_programs(0))
+    def _():
+        fetch(step + 1, 1 - slot)
+
+    for o in range(2):              # a wait needs only the copy's shape
+        for s in range(rows):
+            copy(o, s, 0, slot).wait()
+
+    row = jax.lax.broadcasted_iota(jnp.int32, (rows, span), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, span), 1)
+
+    def window(starts, stops, buf):
+        shift = jnp.zeros((rows, span), jnp.int32)
+        valid = jnp.zeros((rows, span), jnp.int32)
+        for s in range(rows):
+            start = starts[step * rows + s]
+            shift = jnp.where(row == s, start - origin(start), shift)
+            valid = jnp.where(row == s, stops[step * rows + s] - start, valid)
+            stage[pl.ds(s, 1), :] = buf[slot, s]
+        keys = stage[...]
+        for bit in range(span.bit_length()):        # keys[t] ← keys[t + shift]
+            keys = jnp.where((shift >> bit) & 1 == 1,
+                             pltpu.roll(keys, span - (1 << bit), 1), keys)
+        return jnp.where(lane < valid, keys, fill)[:, :width]
+
+    lo, hi = merge_sorted_network(window(a_start, a_stop, a_buf),
+                                  window(b_start, b_stop, b_buf), width,
+                                  roll=pltpu.roll)
+    lo_ref[...] = lo
+    hi_ref[...] = hi
+
+
+@functools.partial(jax.jit, static_argnames=("width", "rows", "interpret"))
+def merge_windows_pallas(a: jax.Array, b: Optional[jax.Array], a_start,
+                         a_stop, b_start, b_stop, *, width: int,
+                         rows: int = 8, interpret: bool = False):
+    """Pallas c1_merge over windows of 32-bit keys (``ref.merge_sorted``
+    with ``windows``): window k of ``a`` is ``a.ravel()[a_start[k]:]``
+    cut to ``width`` keys, keys from ``a_stop[k]`` on read as the dtype's
+    largest key (``b`` likewise; ``b=None`` reads ``a``). The windows'
+    offsets are prefetched scalars; returns (lo, hi), each (windows,
+    width)."""
+    _check_pow2(width, "width")
+    if width % LANES or a.dtype.itemsize != 4:
+        raise ValueError(f"windows of {width} {a.dtype} keys are not whole "
+                         f"32-bit lane tiles")
+    span = width + LANES
+
+    def keys(x):
+        flat = x.reshape(-1)
+        size = max(span, -(-flat.size // LANES) * LANES)
+        return jnp.pad(flat, (0, size - flat.size)).reshape(1, size)
+
+    a2 = keys(a)
+    b2 = a2 if b is None else keys(b)
+    n = a_start.shape[0]
+    pad = (-n) % rows
+    offsets = [jnp.pad(v.astype(jnp.int32), (0, pad))
+               for v in (a_start, a_stop, b_start, b_stop)]
+    fill = max_key(a.dtype)
+    block = pl.BlockSpec((rows, width), lambda g, *_: (g, 0))
+    shp = jax.ShapeDtypeStruct((n + pad, width), a.dtype)
+    lo, hi = pl.pallas_call(
+        functools.partial(_merge_windows_body, width, rows, span, fill),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4, grid=((n + pad) // rows,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 2,
+            out_specs=(block, block),
+            scratch_shapes=[pltpu.VMEM((2, rows, 1, span), a.dtype),
+                            pltpu.VMEM((2, rows, 1, span), a.dtype),
+                            pltpu.SemaphoreType.DMA((2, 2, rows)),
+                            pltpu.VMEM((rows, span), a.dtype)]),
+        out_shape=(shp, shp),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(*offsets, a2, b2)
+    return lo[:n], hi[:n]
 
 
 # ---------------------------------------------------------------------------

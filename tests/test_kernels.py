@@ -68,12 +68,147 @@ def test_mergesort_app():
                                       np.sort(np.asarray(x), axis=-1))
 
 
-def test_mergesort_large_fallback():
-    # above max_kernel_width the base core (XLA sort) finishes the levels
-    x = arr((1, 16384), jnp.float32)
-    got = ops.sortnet_mergesort(x, max_kernel_width=1024, mode="interpret")
-    np.testing.assert_array_equal(np.asarray(got),
-                                  np.sort(np.asarray(x), axis=-1))
+def mergesort_keys(keys: str, shape, dtype) -> np.ndarray:
+    """Keys for the merge-path mergesort: random, over the dtype's whole
+    range with its extremes planted, four distinct values, or (reversed)
+    sorted rows."""
+    dt = np.dtype(dtype)
+    if keys == "ties":
+        return RNG.integers(-2, 2, shape).astype(dt)
+    if keys == "full_range":
+        if dt.kind == "i":
+            info = np.iinfo(dt)
+            x = RNG.integers(info.min, info.max, shape, dtype=np.int64,
+                             endpoint=True).astype(dt)
+            ends = [info.min, info.max, info.min, info.max]
+        else:
+            info = np.finfo(dt)
+            x = (RNG.uniform(-1, 1, shape) * info.max).astype(dt)
+            ends = [info.min, info.max, -np.inf, np.inf]
+        flat = x.reshape(-1)
+        flat[RNG.choice(flat.size, len(ends), replace=False)] = ends
+        return x
+    x = np.asarray(arr(shape, dtype))
+    if keys == "sorted":
+        return np.sort(x, axis=-1)
+    if keys == "reversed":
+        return np.sort(x, axis=-1)[..., ::-1].copy()
+    return x
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.int32])
+@pytest.mark.parametrize("keys,shape,max_width", [
+    ("random", (1, 16384), 1024), ("random", (3, 4096), 1024),
+    ("full_range", (1, 4096), 64), ("full_range", (3, 2048), 256),
+    ("ties", (1, 4096), 256), ("ties", (3, 2048), 64),
+    ("sorted", (1, 2048), 64), ("sorted", (3, 2048), 256),
+    ("reversed", (1, 4096), 256), ("reversed", (3, 2048), 64),
+    ("random", (1, 4096), 1000),
+])
+def test_mergesort_merge_path(keys, shape, max_width, dtype):
+    # above max_kernel_width the levels run as merge-path windows on c1_merge
+    x = mergesort_keys(keys, shape, dtype)
+    got = ops.sortnet_mergesort(jnp.asarray(x), max_kernel_width=max_width,
+                                mode="interpret")
+    assert got.shape == x.shape
+    np.testing.assert_array_equal(np.asarray(got), np.sort(x, axis=-1))
+
+
+def merge_path_runs(order: str, pairs: int, w: int, distinct: int):
+    """Sorted run pairs (pairs, 2, w): random keys with ties, or ``b``
+    wholly after or before ``a``, or one key of ``a`` first and the rest
+    after all of ``b`` (co-rank 1 at diagonal w: the search's longest
+    path)."""
+    runs = np.sort(RNG.integers(0, distinct, (pairs, 2, w)), axis=-1)
+    if order == "b_after":
+        runs[:, 1] += distinct
+    elif order == "b_before":
+        runs[:, 0] += distinct
+    elif order == "one_a_first":
+        runs[:, 0] = distinct + 1
+        runs[:, 0, 0] = -1
+        runs[:, 1] = np.sort(RNG.integers(0, distinct + 1, (pairs, w)))
+    return runs
+
+
+@pytest.mark.parametrize("order,w,block,pairs,distinct", [
+    ("random", 64, 16, 1, 4), ("random", 256, 32, 3, 1000),
+    ("random", 128, 64, 2, 2), ("random", 512, 128, 1, 7),
+    ("b_after", 64, 16, 2, 50), ("b_before", 128, 32, 2, 50),
+    ("one_a_first", 64, 16, 2, 3), ("one_a_first", 256, 128, 1, 1000),
+])
+def test_merge_path_windows_blocks(order, w, block, pairs, distinct):
+    """Window pair k, merged, holds output block k of each run pair in
+    its ``block`` smallest keys."""
+    runs = merge_path_runs(order, pairs, w, distinct)
+    x = jnp.asarray(runs.reshape(-1), jnp.int32)
+    a_start, a_stop, b_start, b_stop = ops._merge_path(x, w, block=block)
+    a_win = ref.window_keys(x, a_start, a_stop, block)
+    b_win = ref.window_keys(x, b_start, b_stop, block)
+    assert a_win.shape == b_win.shape == (pairs * 2 * w // block, block)
+    merged = np.sort(np.concatenate([np.asarray(a_win), np.asarray(b_win)],
+                                    axis=-1), axis=-1)[:, :block]
+    want = np.sort(runs.reshape(pairs, 2 * w), axis=-1)
+    np.testing.assert_array_equal(merged.reshape(pairs, 2 * w), want)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.int32])
+@pytest.mark.parametrize("n_win,width,size", [(16, 128, 4096), (5, 256, 300),
+                                              (8, 128, 128)])
+def test_merge_windows_kernel(n_win, width, size, dtype):
+    """c1_merge over windows (the kernel's lane-tile DMAs, shifts and
+    padding) against the oracle, windows anywhere in the keys and cut
+    anywhere, the array's end included."""
+    keys = jnp.sort(arr((size,), dtype))
+    def offsets():
+        start = RNG.integers(0, size + 1, n_win)
+        stop = np.minimum(start + RNG.integers(0, 2 * width, n_win), size)
+        return jnp.asarray(start, jnp.int32), jnp.asarray(stop, jnp.int32)
+    windows = (*offsets(), *offsets())
+    got = ops.merge_sorted(keys, keys, width=width, windows=windows,
+                           mode="interpret")
+    want = ref.merge_sorted(keys, keys, width=width, windows=windows)
+    for g, r in zip(got, want):
+        assert g.shape == (n_win, width)
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(r))
+
+
+def test_mergesort_rejects_kernel_width_below_two_key_blocks():
+    with pytest.raises(ValueError, match="max_kernel_width"):
+        ops.sortnet_mergesort(arr((1, 64), jnp.int32), max_kernel_width=2,
+                              mode="interpret")
+
+
+def _mergepath_levels() -> int:
+    from repro.obs.metrics import REGISTRY
+    return REGISTRY.get("repro_mergesort_mergepath_levels_total").value
+
+
+@pytest.mark.parametrize("n,max_width", [(4096, 64), (2048, 256),
+                                         (1024, 1024)])
+def test_mergesort_merge_path_launches(n, max_width):
+    """Every merge level launches c1_merge once: log2(n / base) launches,
+    log2(n / max_kernel_width) of them counted as merge-path levels."""
+    from repro.core import isa
+    x = arr((2, n), jnp.int32)
+    levels0 = _mergepath_levels()
+    merges0 = isa.registry.dispatch_counts[("c1_merge", "interpret")]
+    ops.sortnet_mergesort(x, base_width=8, max_kernel_width=max_width,
+                          mode="interpret")
+    assert _mergepath_levels() - levels0 == int(np.log2(n // max_width))
+    assert (isa.registry.dispatch_counts[("c1_merge", "interpret")]
+            - merges0) == int(np.log2(n // 8))
+
+
+@pytest.mark.parametrize("mode,has_sort", [("interpret", False),
+                                           ("ref", True)])
+def test_mergesort_lowering_sort_ops(mode, has_sort):
+    """The kernel path lowers to no sort op; the oracle path, which sorts,
+    shows that the check can see one."""
+    x = arr((1, 1024), jnp.int32)
+    text = jax.jit(lambda v: ops.sortnet_mergesort(
+        v, max_kernel_width=64, mode=mode)).lower(x).as_text()
+    assert ("stablehlo.sort" in text) == has_sort
 
 
 # ---------------------------------------------------------------------------

@@ -52,6 +52,18 @@ CASES = {
                 [((1 << 24,), I32)]),
     "c1_merge": (lambda a, b: ops.merge_sorted(a, b, mode="kernel"),
                  [((1 << 12, 2048), I32), ((1 << 12, 2048), I32)]),
+    # one merge-path level of the 2^24-key mergesort: 8192 windows
+    "c1_merge_windows": (
+        lambda x, *win: ops.merge_sorted(x, x, width=2048, windows=win,
+                                         mode="kernel"),
+        [((1 << 24,), I32)] + [((8192,), I32)] * 4),
+    "c1_merge_windows_f32": (
+        lambda x, *win: ops.merge_sorted(x, x, width=2048, windows=win,
+                                         mode="kernel"),
+        [((8192, 2048), F32)] + [((8192,), I32)] * 4),
+    "mergesort_merge_path": (
+        lambda x: ops.sortnet_mergesort(x, mode="kernel"),
+        [((1 << 16,), I32)]),
     "c5_topk_router8": (lambda x: ops.topk(x, 2, mode="kernel"),
                         [((4096, 8), F32)]),
     "c3_prefixsum": (lambda x: ops.prefix_sum(x, mode="kernel"),
