@@ -13,6 +13,14 @@ step; the prefill's ``KERNELS`` and ``kernels()`` name and count the
 kernels read in the trace). The reference, ``chipbench/reference/
 <config>.py``, draws the weights and runs the plain forward.
 
+A prefill work file's ``work`` takes ``batch``, ``seq``, ``weight_bytes``
+and the sizes as keywords; a decode work file's ``work`` takes ``batch``,
+``weight_bytes``, ``context`` (the mean over the window's decode steps of
+the cache positions filled when each step ran, so that a cache that grows
+with the context, such as attention's keys and values, can be counted)
+and the sizes. Each returns (operations, bytes) of one call and ignores
+the keywords it does not need.
+
 Closed loop: one client sends a batch of prompts, takes ``gen`` greedy
 tokens, and sends the next batch. With ``"prefill_in_setup": true`` the
 first batch is prefilled during set-up and the window only decodes it (a
@@ -88,7 +96,8 @@ class Driver:
         self.batch, self.prompt_len, self.gen = (t["batch"], t["prompt_len"],
                                                  t["gen"])
         self.check_rows = t["check_rows"]
-        self.records = {"ttft_s": [], "decode_s": 0.0, "decode_steps": 0}
+        self.records = {"ttft_s": [], "decode_s": 0.0, "decode_steps": 0,
+                        "decode_positions": 0}
         self.counters = {}
         self.work = {}
         self.served: dict[int, np.ndarray] = {}     # batch → (B, tokens)
@@ -205,6 +214,7 @@ class Driver:
                     index += 1
                     t_dec = time.perf_counter()
                     continue
+                rec["decode_positions"] += self.pos
                 with spans("decode_step"):
                     self._decode_one()
                 rec["decode_steps"] += 1
@@ -222,7 +232,10 @@ class Driver:
         f, _ = w_pre.work(batch=self.batch, seq=self.prompt_len,
                           weight_bytes=weight_bytes, **s)
         rec["prefill_flops"] = f * n_prefill
-        f, b = w_dec.work(batch=self.batch, weight_bytes=weight_bytes, **s)
+        rec["decode_context"] = (rec["decode_positions"] / rec["decode_steps"]
+                                 if rec["decode_steps"] else 0.0)
+        f, b = w_dec.work(batch=self.batch, weight_bytes=weight_bytes,
+                          context=rec["decode_context"], **s)
         rec["decode_step_flops"], rec["decode_step_bytes"] = f, b
         self.work = {k: [f * n_prefill, b * n_prefill] for k, (f, b) in
                      w_pre.kernels(batch=self.batch, seq=self.prompt_len,

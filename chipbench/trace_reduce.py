@@ -1,10 +1,17 @@
 """Reduce a JAX profiler trace (``.xplane.pb``) to the benchmark's
 device numbers: busy and idle share of the traced window, device time per
 operation and per kernel, and the device's idle time split by what the
-host was doing then (the harness's own ``cb.*`` annotations).
+host was doing then (the harness's own ``cb.*`` annotations), and device
+time by the op-name path of each op (the names of its
+``jax.named_scope`` blocks, and ``while/body`` inside a scan or loop).
 
 Only the process that held the chip can write the trace; this module
-only reads it, with ``jax.profiler.ProfileData`` and nothing else.
+only reads it, with ``jax.profiler.ProfileData`` and, for the op-name
+paths, a reader of the serialized trace's event metadata: on a TPU v5e
+each ``XLA Ops`` event's metadata carries the path as the stat ``tf_op``
+(``jit(<lambda>)/while/body/closed_call/bsd,de->bse/dot_general:`` in a
+Mamba2 decode step), and ``ProfileData`` gives an event only the stats
+of its own, not those of its metadata.
 """
 from __future__ import annotations
 
@@ -13,12 +20,13 @@ import dataclasses
 import glob
 import os
 import re
-from typing import Iterable, Optional
+from typing import Callable, Hashable, Iterable, Optional
 
 ANNOTATION_PREFIX = "cb."        # host spans the harness writes
 WINDOW = "cb.window"             # the span around the traced window
 DEVICE_PLANE = re.compile(r"^/device:(TPU|GPU):(\d+)$")
 OPS_LINE = "XLA Ops"
+SCOPE_STAT = "tf_op"             # event-metadata stat with the op-name path
 
 
 @dataclasses.dataclass
@@ -26,6 +34,7 @@ class Event:
     name: str
     start_ns: float
     end_ns: float
+    scope: str = ""          # a device op's op-name path ("" where unknown)
 
 
 @dataclasses.dataclass
@@ -50,10 +59,13 @@ def op_name(hlo: str) -> str:
     return re.sub(r"\.\d+$", "", name)
 
 
-def self_times(events: list[Event], lo: float, hi: float) -> dict[str, float]:
-    """Device seconds per op name inside [lo, hi], each op's own time: the
-    ops of a loop body nest inside the loop's event on the same line, and
-    only the innermost op is running."""
+def self_times(events: list[Event], lo: float, hi: float,
+               key: Callable[[Event], Hashable] = lambda e: op_name(e.name)
+               ) -> dict:
+    """Device seconds per op name (or per ``key`` of each op) inside
+    [lo, hi], each op's own time: the ops of a loop body nest inside the
+    loop's event on the same line, and only the innermost op is
+    running."""
     out: collections.Counter = collections.Counter()
     stack: list[list] = []          # [event, child seconds]
 
@@ -61,7 +73,7 @@ def self_times(events: list[Event], lo: float, hi: float) -> dict[str, float]:
         e, child = entry
         own = (min(e.end_ns, hi) - max(e.start_ns, lo)) * 1e-9 - child
         if own > 0:
-            out[op_name(e.name)] += own
+            out[key(e)] += own
         if stack:
             stack[-1][1] += (min(e.end_ns, hi) - max(e.start_ns, lo)) * 1e-9
 
@@ -76,17 +88,93 @@ def self_times(events: list[Event], lo: float, hi: float) -> dict[str, float]:
     return dict(out)
 
 
-def from_profile(pd) -> Trace:
+def _varint(buf, i: int) -> tuple[int, int]:
+    value = shift = 0
+    while True:
+        b = buf[i]
+        i += 1
+        value |= (b & 0x7F) << shift
+        if b < 0x80:
+            return value, i
+        shift += 7
+
+
+def _fields(buf):
+    """(field number, value) of each field of one serialized protobuf
+    message: an int for a varint, a ``memoryview`` otherwise."""
+    i, n = 0, len(buf)
+    while i < n:
+        tag, i = _varint(buf, i)
+        wire = tag & 7
+        if wire == 0:
+            value, i = _varint(buf, i)
+        elif wire == 2:
+            size, i = _varint(buf, i)
+            value, i = buf[i:i + size], i + size
+        elif wire in (1, 5):
+            size = 8 if wire == 1 else 4
+            value, i = buf[i:i + size], i + size
+        else:
+            raise ValueError(f"unsupported protobuf wire type {wire}")
+        yield tag >> 3, value
+
+
+def op_scopes(xspace: bytes) -> dict[str, dict[str, str]]:
+    """device plane name → {op event name → its ``SCOPE_STAT`` path}, read
+    from a serialized ``XSpace`` (``tsl/profiler/protobuf/xplane.proto``:
+    a plane's ``event_metadata`` map is field 4 and ``stat_metadata`` field
+    5; an event metadata's ``name`` is field 2 and its ``stats`` field 5; a
+    stat's ``metadata_id`` is field 1, ``str_value`` 5 and ``ref_value``
+    7). The ops' lines are skipped unread. An event name whose metadata
+    entries carry different paths, or none, maps to ``""``."""
+    out = {}
+    for field, plane in _fields(memoryview(xspace)):
+        if field != 1:
+            continue
+        name, stat_names, metas = "", {}, []
+        for f, v in _fields(plane):
+            if f == 2:
+                name = bytes(v).decode()
+            elif f in (4, 5):
+                entry = dict(_fields(v))
+                if 2 not in entry:
+                    continue
+                value = dict(_fields(entry[2]))
+                if f == 5:
+                    stat_names[value.get(1, 0)] = bytes(
+                        value.get(2, b"")).decode()
+                else:
+                    stats = [dict(_fields(st)) for k, st in _fields(entry[2])
+                             if k == 5]
+                    metas.append((bytes(value.get(2, b"")).decode(), stats))
+        if not DEVICE_PLANE.match(name):
+            continue
+        paths: dict[str, set] = {}
+        for op, stats in metas:
+            path = next(((bytes(st[5]).decode() if 5 in st
+                          else stat_names.get(st.get(7), ""))
+                         for st in stats
+                         if stat_names.get(st.get(1)) == SCOPE_STAT), "")
+            paths.setdefault(op, set()).add(path)
+        out[name] = {op: p.pop() if len(p) == 1 else ""
+                     for op, p in paths.items()}
+    return out
+
+
+def from_profile(pd, scopes: Optional[dict] = None) -> Trace:
     """Collect device ops (the ``XLA Ops`` line of each device plane) and
-    the harness's host annotations from a ``ProfileData``."""
+    the harness's host annotations from a ``ProfileData``; ``scopes``
+    (``op_scopes`` of the same trace) gives each device op its path."""
     device_ops: dict[int, list[Event]] = {}
     host: list[Event] = []
     for plane in pd.planes:
         m = DEVICE_PLANE.match(plane.name)
         for line in plane.lines:
             if m and line.name == OPS_LINE:
+                paths = (scopes or {}).get(plane.name, {})
                 evs = device_ops.setdefault(int(m.group(2)), [])
-                evs.extend(Event(e.name, e.start_ns, e.end_ns)
+                evs.extend(Event(e.name, e.start_ns, e.end_ns,
+                                 paths.get(e.name, ""))
                            for e in line.events)
             elif not m:
                 host.extend(Event(e.name, e.start_ns, e.end_ns)
@@ -102,7 +190,10 @@ def load(path: str) -> Trace:
     from jax.profiler import ProfileData
     if os.path.isdir(path):
         path = find_xplane(path)
-    return from_profile(ProfileData.from_file(path))
+    with open(path, "rb") as f:
+        xspace = f.read()
+    return from_profile(ProfileData.from_serialized_xspace(xspace),
+                        op_scopes(xspace))
 
 
 def window_of(trace: Trace) -> tuple[float, float]:
@@ -220,6 +311,26 @@ def kernel_seconds(red: Reduction, patterns: dict[str, str]
         if t > 0:
             out[kernel] = t
     return out
+
+
+def scope_seconds(trace: Trace, devices: list[int], component: str
+                  ) -> Optional[float]:
+    """Device self time inside ``cb.window``, averaged over ``devices``, of
+    the ops whose op-name path has ``component`` as one of its parts (a
+    ``jax.named_scope``'s name, or ``while`` for the body of a scan or
+    loop), nesting as in ``self_times``. None where no op in the window
+    has it, as in a trace whose ops carry no paths."""
+    lo, hi = window_of(trace)
+    parts: dict[str, bool] = {}
+
+    def inside(e: Event) -> bool:
+        if e.scope not in parts:
+            parts[e.scope] = component in re.split(r"[/:]", e.scope)
+        return parts[e.scope]
+
+    total = sum(self_times(trace.device_ops.get(d, []), lo, hi,
+                           inside).get(True, 0.0) for d in devices)
+    return total / len(devices) if total > 0 else None
 
 
 def breakdown(red: Reduction, top: int = 10) -> dict:

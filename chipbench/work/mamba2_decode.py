@@ -6,7 +6,8 @@ unembedding), the f32 SSM state (batch, heads, headdim, state) of every
 layer read and written, and the convolution window of x, B and C read and
 written. Operations: the projections, convolution, state update and
 readout per token and layer, and the unembedding. Sizes a count does not
-need are ignored."""
+need are ignored, and so is ``context``: the state and the convolution
+window have the same size at every position."""
 
 
 def work(batch: int, n_layers: int, d_model: int, d_inner: int, state: int,

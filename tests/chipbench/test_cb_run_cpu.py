@@ -35,15 +35,19 @@ def tiny_cell(name):
     return cell
 
 
-def tiny_model(**over):
+def tiny_model(cell, **over):
+    """The cell's own architecture (its configuration's ``program.arch``)
+    at the program's reduced size, with two layers and ``over``."""
     from repro.configs import get_config
-    return dataclasses.replace(get_config("mamba2-1.3b").reduced(),
+    arch = cell.config["program"]["arch"]
+    return dataclasses.replace(get_config(arch).reduced(),
                                **({"n_layers": 2} | over))
 
 
 def on_the_cpu(monkeypatch, cell, cfg=None):
     """Skip the harness's look for a TPU (the CPU's devices stand in, with
-    the v5e's peaks), and serve a tiny model in place of the cell's."""
+    the v5e's peaks), and serve a tiny model of the cell's architecture in
+    place of the cell's."""
     from chipbench import peaks
     devs = jax.devices()[:cell.chips]
     monkeypatch.setattr(run, "device_info", lambda chips: (devs, {
@@ -53,7 +57,7 @@ def on_the_cpu(monkeypatch, cell, cfg=None):
                         lambda kind: peaks.PEAKS["TPU v5 lite"])
     mod = harness.load_module("drivers", cell.config["program"]["driver"])
     if hasattr(mod, "program_config"):
-        model = cfg if cfg is not None else tiny_model()
+        model = cfg if cfg is not None else tiny_model(cell)
         monkeypatch.setattr(mod, "program_config", lambda program: model)
     return mod
 
@@ -121,20 +125,29 @@ def _state_unchanged(monkeypatch):
 
 
 def _token_altered(monkeypatch):
-    """Row 0's token is one past the greedy choice, where it is sampled."""
+    """Every row's token is one past the greedy choice, where it is
+    sampled, so that whichever rows the check samples hold it."""
     from repro.launch import serve
     real = serve.sample
 
     def broken(logits, rng, temperature):
-        tok = real(logits, rng, temperature)
-        return tok.at[0].set((tok[0] + 1) % logits.shape[-1])
+        return (real(logits, rng, temperature) + 1) % logits.shape[-1]
 
     monkeypatch.setattr(serve, "sample", broken)
 
 
-FAULTS = [(name, fault) for name in CELLS for fault in (
-    ["answer_altered"] if name.startswith("stream") else
-    ["state_unchanged", "token_altered"])]
+# the faults the timed path of each driver can have
+DRIVER_FAULTS = {"sched_programs": ["answer_altered"],
+                 "lm_serve": ["state_unchanged", "token_altered"]}
+
+
+def driver_of(name):
+    cell = harness.cell_from(harness.load_benchmark(), name)
+    return cell.config["program"]["driver"]
+
+
+FAULTS = [(name, fault) for name in CELLS
+          for fault in DRIVER_FAULTS[driver_of(name)]]
 
 
 @pytest.mark.parametrize("name,fault", FAULTS)
@@ -143,6 +156,61 @@ def test_check_catches_a_broken_timed_path(name, fault, monkeypatch):
      "token_altered": _token_altered}[fault](monkeypatch)
     res = run_tiny(name, monkeypatch)
     assert res["correct"] is False, res["compared"]
+
+
+@pytest.mark.parametrize("name", ["mamba2-1.3b.prefill",
+                                  "mamba2-1.3b.decode"])
+def test_tiny_model_is_the_cells_own_architecture(name):
+    from repro.configs import get_config
+    cell = tiny_cell(name)
+    assert tiny_model(cell) == dataclasses.replace(
+        get_config("mamba2-1.3b").reduced(), n_layers=2)
+    cell.config["program"]["arch"] = "hymba-1.5b"
+    model = tiny_model(cell, vocab=256)
+    assert model.family == "hybrid"
+    assert (model.n_layers, model.vocab) == (2, 256)
+
+
+def test_faults_follow_the_cells_driver():
+    today = {("stream-apps.bulk", "answer_altered"),
+             ("mamba2-1.3b.prefill", "state_unchanged"),
+             ("mamba2-1.3b.prefill", "token_altered"),
+             ("mamba2-1.3b.decode", "state_unchanged"),
+             ("mamba2-1.3b.decode", "token_altered")}
+    names = {name for name, _ in today}
+    assert {f for f in FAULTS if f[0] in names} == today
+
+
+def test_decode_work_gets_the_mean_context_of_the_window(monkeypatch):
+    cell = tiny_cell("mamba2-1.3b.decode")
+    drv = on_the_cpu(monkeypatch, cell).Driver(cell, SEED, jax.devices()[:1])
+    work = harness.load_module("work", cell.config["program"]["work"]["decode"])
+    got = {}
+    real_work = work.work
+
+    def counting_work(**kw):
+        got.update(kw)
+        return real_work(**kw)
+
+    monkeypatch.setattr(work, "work", counting_work)
+    drv.setup(1.0)
+    try:
+        stepped = []
+        real_step = drv._decode_one
+
+        def step():
+            stepped.append(drv.pos)
+            real_step()
+
+        drv._decode_one = step
+        drv.window(1.0, harness.Spans(False))
+    finally:
+        drv.release()
+    rec = drv.records
+    assert len(stepped) == rec["decode_steps"] > 0
+    assert rec["decode_context"] == pytest.approx(sum(stepped) / len(stepped))
+    assert rec["decode_context"] >= cell.traffic["prompt_len"]
+    assert got["context"] == rec["decode_context"]
 
 
 def test_stream_control_in_bfloat16_fails_the_check(monkeypatch):
@@ -206,9 +274,9 @@ def test_mamba2_control_in_float8_fails_the_check(monkeypatch):
     cell = tiny_cell("mamba2-1.3b.prefill")
     cell.traffic = dict(cell.traffic, batch=4, prompt_len=256, gen=16,
                         check_rows=4, check_block=4)
-    cfg = tiny_model(n_layers=16, d_model=256, ssm_state=32, ssm_headdim=32,
-                     ssm_chunk=64, vocab=2048, param_dtype="bfloat16",
-                     act_dtype="bfloat16")
+    cfg = tiny_model(cell, n_layers=16, d_model=256, ssm_state=32,
+                     ssm_headdim=32, ssm_chunk=64, vocab=2048,
+                     param_dtype="bfloat16", act_dtype="bfloat16")
     mod = on_the_cpu(monkeypatch, cell, cfg)
     drv = mod.Driver(cell, SEED, jax.devices()[:1])
     drv.setup(1.0)

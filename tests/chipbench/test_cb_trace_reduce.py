@@ -1,5 +1,6 @@
 """The reduction from a profiler trace to the benchmark's device numbers,
-on a hand-made trace whose answers are known (data/two_kernels.pbtxt)."""
+on hand-made traces whose answers are known (data/two_kernels.pbtxt, and
+data/scoped_ops.pbtxt for the ops' op-name paths)."""
 import os
 
 import pytest
@@ -8,7 +9,21 @@ from chipbench import harness, peaks, trace_reduce
 from chipbench.layer_metrics import _common
 
 DATA = os.path.join(os.path.dirname(__file__), "data", "two_kernels.pbtxt")
+SCOPED = os.path.join(os.path.dirname(__file__), "data", "scoped_ops.pbtxt")
 US = 1e-6
+
+
+def written(text_path, tmp_path_factory):
+    """The hand-made trace as the profiler writes it: a serialized XSpace
+    in an ``.xplane.pb`` file under a log directory."""
+    from jax.profiler import ProfileData
+    with open(text_path) as f:
+        xspace = ProfileData.text_proto_to_serialized_xspace(f.read())
+    log_dir = tmp_path_factory.mktemp("trace")
+    path = log_dir / "plugins" / "profile" / "1" / "host.xplane.pb"
+    path.parent.mkdir(parents=True)
+    path.write_bytes(xspace)
+    return str(log_dir)
 
 
 @pytest.fixture(scope="module")
@@ -100,3 +115,66 @@ def test_op_name_strips_the_hlo_text():
         "%merge_sorted_pallas.1 = (s32[8]) custom-call(s32[8] %a)") == \
         "merge_sorted_pallas"
     assert trace_reduce.op_name("%while = (s32[]) while(...)") == "while"
+
+
+@pytest.fixture(scope="module")
+def scoped(tmp_path_factory):
+    return trace_reduce.load(written(SCOPED, tmp_path_factory))
+
+
+def test_device_ops_carry_their_op_name_paths(scoped):
+    assert [e.scope for e in scoped.device_ops[0]] == [
+        "", "jit(step)/while/body/attn/dot_general:",
+        "jit(step)/while/body/ssm/mul:",
+        "jit(step)/while/body/mlp/pallas_call:", "jit(step)/copy:",
+        "",             # two metadata entries of this name, two paths
+        "jit(step)/while/body/attn/dot_general:"]
+    assert [e.scope for e in scoped.device_ops[1]] == [
+        "jit(step)/while/body/attn/dot_general:"]
+    assert all(e.scope == "" for e in scoped.host_spans)
+
+
+@pytest.mark.parametrize("component,seconds", [
+    # the loop's own 17 us carry no path; the last attention op is
+    # clipped to the window's 5 us
+    ("while", 38 * US), ("body", 38 * US), ("attn", 13 * US),
+    ("ssm", 15 * US), ("mlp", 10 * US), ("copy", 10 * US),
+    ("dot_general", 13 * US), ("jit(step)", 48 * US)])
+def test_scope_seconds_by_path_component(scoped, component, seconds):
+    assert trace_reduce.scope_seconds(scoped, [0], component) == \
+        pytest.approx(seconds)
+
+
+def test_scope_seconds_is_the_mean_over_devices(scoped):
+    assert trace_reduce.scope_seconds(scoped, [0, 1], "attn") == \
+        pytest.approx((13 + 10) / 2 * US)
+
+
+@pytest.mark.parametrize("component", ["nothing", "jit(sample)", "tf_op",
+                                       "at"])
+def test_scope_seconds_finds_nothing_where_no_op_has_the_part(scoped,
+                                                              component):
+    assert trace_reduce.scope_seconds(scoped, [0], component) is None
+
+
+def test_scope_seconds_is_none_where_the_ops_carry_no_paths(trace):
+    assert all(e.scope == "" for e in trace.device_ops[0])
+    assert trace_reduce.scope_seconds(trace, [0], "while") is None
+
+
+def test_op_times_of_a_scoped_trace_are_by_op_name(scoped):
+    assert trace_reduce.reduce(scoped, devices=[0]).op_seconds == \
+        pytest.approx({"while": 17 * US, "fusion": 28 * US,
+                       "mlp_pallas": 10 * US, "copy": 15 * US})
+
+
+def test_loading_the_written_trace_leaves_the_reduction_as_it_was(
+        red, tmp_path_factory):
+    loaded = trace_reduce.reduce(
+        trace_reduce.load(written(DATA, tmp_path_factory)), devices=[0])
+    assert loaded == red
+    assert trace_reduce.breakdown(loaded) == trace_reduce.breakdown(red)
+    pats = harness.kernel_patterns(["c0_program", "c3_prefixsum",
+                                    "c4_statescan"])
+    assert trace_reduce.kernel_seconds(loaded, pats) == \
+        trace_reduce.kernel_seconds(red, pats)

@@ -70,6 +70,16 @@ def test_mamba2_1p3b_decode_moves_about_nine_gigabytes_at_batch_32():
     assert 9.0e9 < b < 9.3e9
 
 
+@pytest.mark.parametrize("context", [512.0, 1234.5, 18432.0])
+def test_mamba2_decode_count_does_not_depend_on_the_context(context):
+    # the state and convolution window have one size at every position
+    sizes = dict(batch=32, n_layers=48, d_model=2048, d_inner=4096,
+                 state=128, heads=64, headdim=64, conv_width=4, vocab=50280,
+                 weight_bytes=2.69e9)
+    assert work("mamba2_decode", context=context, **sizes) == \
+        work("mamba2_decode", **sizes)
+
+
 def test_peaks_table_and_least_time():
     p = peaks.peaks_for("TPU v5 lite")
     assert (p.flops_bf16, p.hbm_bytes_s) == (197e12, 819e9)
