@@ -16,7 +16,12 @@ ARCHS = (
     "grok1_314b",
     "musicgen_medium",
     "hymba_1p5b",
+    "falcon_h1_34b",
 )
+
+# one chip's pipeline stage of a published model, ``<arch>-stage``: that
+# module's ``stage()``. Not in ARCHS, which hold whole models.
+STAGES = ("falcon_h1_34b_stage",)
 
 # CLI ids (dashes) ↔ module names (underscores)
 _ALIAS = {a.replace("_", "-"): a for a in ARCHS}
@@ -24,6 +29,10 @@ _ALIAS = {a.replace("_", "-"): a for a in ARCHS}
 
 def get_config(name: str) -> ModelConfig:
     key = name.replace("-", "_").replace(".", "p")
+    if key in STAGES:
+        mod = importlib.import_module(
+            f"repro.configs.{key.removesuffix('_stage')}")
+        return mod.stage()
     if key not in ARCHS:
         raise KeyError(f"unknown arch {name!r}; have {sorted(_ALIAS)}")
     mod = importlib.import_module(f"repro.configs.{key}")
@@ -34,5 +43,5 @@ def all_configs() -> dict[str, ModelConfig]:
     return {a: get_config(a) for a in ARCHS}
 
 
-__all__ = ["ARCHS", "SHAPES", "ModelConfig", "ShapeConfig",
+__all__ = ["ARCHS", "STAGES", "SHAPES", "ModelConfig", "ShapeConfig",
            "cell_applicable", "get_config", "all_configs"]

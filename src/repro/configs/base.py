@@ -40,6 +40,18 @@ class ModelConfig:
     ssm_expand: int = 2
     ssm_chunk: int = 256
     conv_width: int = 4
+    ssm_groups: int = 1         # B/C groups, each shared by heads/groups heads
+    ssm_inner: int = 0          # SSM inner width; 0 = ssm_expand * d_model
+    conv_bias: bool = False
+    # -- μP multipliers (Falcon-H1), applied at run time; 1 adds no op -------
+    embedding_multiplier: float = 1.0
+    lm_head_multiplier: float = 1.0
+    attention_out_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    ssm_in_multiplier: float = 1.0
+    ssm_out_multiplier: float = 1.0
+    ssm_multipliers: tuple = (1.0, 1.0, 1.0, 1.0, 1.0)   # z, x, B, C, dt
+    mlp_multipliers: tuple = (1.0, 1.0)                  # gate, down
     # -- hybrid / attention variants -------------------------------------------
     swa_window: int = 0         # 0 = full attention
     # -- modality frontend (stubbed: precomputed embeddings) -------------------
@@ -71,6 +83,9 @@ class ModelConfig:
             raise ValueError("moe needs n_experts/top_k")
         if self.family in ("ssm", "hybrid") and not self.ssm_state:
             raise ValueError("ssm/hybrid needs ssm_state")
+        if self.has_ssm and self.ssm_heads % self.ssm_groups:
+            raise ValueError(f"{self.ssm_heads} SSM heads do not split into "
+                             f"{self.ssm_groups} groups")
 
     @property
     def vocab_padded(self) -> int:
@@ -78,7 +93,7 @@ class ModelConfig:
 
     @property
     def d_inner(self) -> int:       # SSM inner width
-        return self.ssm_expand * self.d_model
+        return self.ssm_inner or self.ssm_expand * self.d_model
 
     @property
     def ssm_heads(self) -> int:
@@ -86,7 +101,7 @@ class ModelConfig:
 
     @property
     def conv_dim(self) -> int:      # channels through the causal conv
-        return self.d_inner + 2 * self.ssm_state
+        return self.d_inner + 2 * self.ssm_groups * self.ssm_state
 
     @property
     def sub_quadratic(self) -> bool:
@@ -112,8 +127,10 @@ class ModelConfig:
         if self.has_ssm:
             din = self.d_inner
             # in_proj → [z, x, B, C, dt]; out_proj
-            n += l * (d * (2 * din + 2 * self.ssm_state + self.ssm_heads)
-                      + din * d + self.conv_dim * self.conv_width + din)
+            gn = self.ssm_groups * self.ssm_state
+            n += l * (d * (2 * din + 2 * gn + self.ssm_heads) + din * d
+                      + self.conv_dim * (self.conv_width + self.conv_bias)
+                      + din)
         if self.d_ff:
             mats = 3 if self.mlp_gated else 2
             n += l * mats * d * self.d_ff
@@ -153,6 +170,10 @@ class ModelConfig:
             ssm_state=16 if self.ssm_state else 0,
             ssm_headdim=16 if self.ssm_state else 64,
             ssm_chunk=16,
+            # a decoupled inner width stays decoupled (64, not 2 * 64), with
+            # two 16-wide heads to each of at most two groups
+            ssm_inner=64 if self.ssm_inner else 0,
+            ssm_groups=min(self.ssm_groups, 2),
             swa_window=min(self.swa_window, 32) if self.swa_window else 0,
             attn_chunk=32,
             param_dtype="float32",

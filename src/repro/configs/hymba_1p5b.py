@@ -1,6 +1,11 @@
 """Hymba-1.5B [arXiv:2411.13676] — hybrid: parallel attention + mamba
 heads in every block, SWA for the attention half. 25 heads % 16 != 0 →
-CP fallback for the attention half."""
+CP fallback for the attention half.
+
+Not Hymba's published block: the SSD heads are 50 wide, there are no meta
+tokens and no cross-layer KV sharing, every layer is windowed, and the two
+halves are summed as every hybrid here sums them (Falcon-H1's block with
+unit multipliers), where Hymba normalises each and takes a learned mean."""
 from .base import ModelConfig
 
 

@@ -24,16 +24,17 @@ from repro.configs.base import ModelConfig
 from repro.distributed.sharding import constrain
 from repro.kernels import ops as kops
 
-from .layers import apply_rope, rmsnorm
+from .layers import apply_rope, rmsnorm, scaled
 
 NEG_INF = -1e30
 
 
 def _project_qkv(cfg: ModelConfig, p: dict, x: jax.Array,
                  positions: jax.Array):
-    """x: (B, S, D) → q (B,S,H,hd), k/v (B,S,KV,hd), RoPE'd + qk-normed."""
+    """x: (B, S, D) → q (B,S,H,hd), k/v (B,S,KV,hd), RoPE'd + qk-normed;
+    k times the μP ``key_multiplier``."""
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
-    k = jnp.einsum("bsd,dhk->bshk", x, p["wk"])
+    k = scaled(jnp.einsum("bsd,dhk->bshk", x, p["wk"]), cfg.key_multiplier)
     v = jnp.einsum("bsd,dhk->bshk", x, p["wv"])
     if cfg.qk_norm:
         q = rmsnorm(q, p["q_norm"])

@@ -5,6 +5,11 @@ import jax
 import jax.numpy as jnp
 
 
+def scaled(x: jax.Array, m: float) -> jax.Array:
+    """``x · m`` for a constant multiplier; a multiplier of 1 adds no op."""
+    return x if m == 1 else x * m
+
+
 def rmsnorm(x: jax.Array, w: jax.Array, eps: float = 1e-6) -> jax.Array:
     xf = x.astype(jnp.float32)
     var = jnp.mean(xf * xf, axis=-1, keepdims=True)
@@ -29,15 +34,19 @@ def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     return out.astype(x.dtype)
 
 
-def mlp(p: dict, x: jax.Array, gated: bool) -> jax.Array:
+def mlp(p: dict, x: jax.Array, gated: bool,
+        multipliers: tuple = (1.0, 1.0)) -> jax.Array:
+    """``multipliers``: (gate, down), Falcon-H1's μP constants on the gate
+    projection (before the SiLU) and on the output."""
+    gate_m, down_m = multipliers
     if gated:  # SwiGLU
-        g = jnp.einsum("...d,df->...f", x, p["w_gate"])
+        g = scaled(jnp.einsum("...d,df->...f", x, p["w_gate"]), gate_m)
         h = jnp.einsum("...d,df->...f", x, p["w_in"])
         a = jax.nn.silu(g.astype(jnp.float32)).astype(x.dtype) * h
     else:      # GPT-style 2-matrix GELU
         h = jnp.einsum("...d,df->...f", x, p["w_in"])
         a = jax.nn.gelu(h.astype(jnp.float32)).astype(x.dtype)
-    return jnp.einsum("...f,fd->...d", a, p["w_out"])
+    return scaled(jnp.einsum("...f,fd->...d", a, p["w_out"]), down_m)
 
 
 def embed_tokens(table: jax.Array, tokens: jax.Array) -> jax.Array:

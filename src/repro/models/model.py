@@ -19,7 +19,8 @@ from repro.distributed.sharding import constrain
 from . import attention as attn
 from . import moe as moe_mod
 from . import ssm as ssm_mod
-from .layers import cross_entropy, embed_tokens, mlp, rmsnorm, unembed
+from .layers import (cross_entropy, embed_tokens, mlp, rmsnorm, scaled,
+                     unembed)
 from .params import abstract_params, init_params, logical_axes  # noqa: F401
 
 
@@ -27,31 +28,56 @@ from .params import abstract_params, init_params, logical_axes  # noqa: F401
 # blocks
 # ---------------------------------------------------------------------------
 
-def _mixer(cfg: ModelConfig, p: dict, h: jax.Array, positions: jax.Array):
-    if cfg.family == "ssm":
-        return ssm_mod.ssd_forward(cfg, p["ssm"], h)
-    if cfg.family == "hybrid":  # Hymba: parallel attention + mamba heads
-        a = attn.attention(cfg, p["attn"], h, positions)
-        s = ssm_mod.ssd_forward(cfg, p["ssm"], h)
-        return (a + s) * 0.5
-    return attn.attention(cfg, p["attn"], h, positions)
+def _mixer(cfg: ModelConfig, p: dict, h: jax.Array, attn_fn, ssm_fn):
+    """The block's token mixer on the normed input ``h``: attention, the
+    SSD, or (hybrid) both in parallel on the same ``h``, summed, each
+    branch with its μP output multiplier and the SSD with its input one
+    (Falcon-H1's block; its attention input multiplier is 1).
+    ``attn_fn(p, h)`` and ``ssm_fn(p, h)`` run one branch as forward,
+    prefill or decode does and return (out, cache entries).
+    Returns (out, cache entries)."""
+    outs, cache = [], {}
+    if cfg.has_attention:
+        with jax.named_scope("attn"):
+            a, c = attn_fn(p["attn"], h)
+            outs.append(scaled(a, cfg.attention_out_multiplier))
+        cache.update(c)
+    if cfg.has_ssm:
+        with jax.named_scope("ssm"):
+            s, c = ssm_fn(p["ssm"], scaled(h, cfg.ssm_in_multiplier))
+            outs.append(scaled(s, cfg.ssm_out_multiplier))
+        cache.update(c)
+    return sum(outs[1:], outs[0]), cache
+
+
+def _block(cfg: ModelConfig, p: dict, x: jax.Array, attn_fn, ssm_fn):
+    """Pre-norm residual block: x + mixer, then x + MLP (or experts).
+    Returns (x, moe aux loss, cache entries)."""
+    y, cache = _mixer(cfg, p, rmsnorm(x, p["norm1"]), attn_fn, ssm_fn)
+    x = x + y
+    aux = jnp.zeros((), jnp.float32)
+    if cfg.d_ff or cfg.n_experts:
+        h2 = rmsnorm(x, p["norm2"])
+        with jax.named_scope("mlp"):
+            if cfg.n_experts:
+                y, aux = moe_mod.moe_layer(cfg, p["moe"], h2)
+            else:
+                y = mlp(p["mlp"], h2, cfg.mlp_gated, cfg.mlp_multipliers)
+        x = x + y
+    return x, aux, cache
+
+
+def _constrain_residual(cfg: ModelConfig, x: jax.Array) -> jax.Array:
+    return constrain(x, ("batch", "seq_sp" if cfg.sp else None, "act_embed"))
 
 
 def block(cfg: ModelConfig, p: dict, x: jax.Array, positions: jax.Array):
     """One transformer/ssm/hybrid block. Returns (x, aux)."""
-    h = rmsnorm(x, p["norm1"])
-    x = x + _mixer(cfg, p, h, positions)
-    aux = jnp.zeros((), jnp.float32)
-    if cfg.d_ff or cfg.n_experts:
-        h2 = rmsnorm(x, p["norm2"])
-        if cfg.n_experts:
-            y, aux = moe_mod.moe_layer(cfg, p["moe"], h2)
-        else:
-            y = mlp(p["mlp"], h2, cfg.mlp_gated)
-        x = x + y
-    x = constrain(x, ("batch", "seq_sp" if cfg.sp else None,
-                      "act_embed"))
-    return x, aux
+    x, aux, _ = _block(
+        cfg, p, x,
+        lambda pa, h: (attn.attention(cfg, pa, h, positions), {}),
+        lambda ps, h: (ssm_mod.ssd_forward(cfg, ps, h), {}))
+    return _constrain_residual(cfg, x), aux
 
 
 def _remat(cfg: ModelConfig, fn):
@@ -84,13 +110,20 @@ def stack(cfg: ModelConfig, layer_params, x: jax.Array,
 # forward / loss
 # ---------------------------------------------------------------------------
 
-def forward(cfg: ModelConfig, params, batch: dict, train: bool):
+def _embed(cfg: ModelConfig, params, tokens: jax.Array) -> jax.Array:
+    """Token embeddings times the μP ``embedding_multiplier``."""
+    x = embed_tokens(params["embed"], tokens).astype(jnp.dtype(cfg.act_dtype))
+    return scaled(x, cfg.embedding_multiplier)
+
+
+def _inputs(cfg: ModelConfig, params, batch: dict) -> jax.Array:
     if "embeddings" in batch:            # stubbed VLM/audio frontend
-        x = batch["embeddings"].astype(jnp.dtype(cfg.act_dtype))
-    else:
-        x = embed_tokens(params["embed"], batch["tokens"])
-        x = x.astype(jnp.dtype(cfg.act_dtype))
-    x = constrain(x, ("batch", None, "act_embed"))
+        return batch["embeddings"].astype(jnp.dtype(cfg.act_dtype))
+    return _embed(cfg, params, batch["tokens"])
+
+
+def forward(cfg: ModelConfig, params, batch: dict, train: bool):
+    x = constrain(_inputs(cfg, params, batch), ("batch", None, "act_embed"))
     s = x.shape[1]
     positions = jnp.arange(s, dtype=jnp.int32)   # uniform across batch
     x, aux = stack(cfg, params["layers"], x, positions, train)
@@ -99,6 +132,13 @@ def forward(cfg: ModelConfig, params, batch: dict, train: bool):
 
 def _unembed_w(cfg: ModelConfig, params):
     return (params["embed"].T if cfg.tie_embeddings else params["unembed"])
+
+
+def logits(cfg: ModelConfig, w: jax.Array, x: jax.Array) -> jax.Array:
+    """f32 logits over the true vocabulary from final-normed hidden states
+    and the unembedding ``w`` (``_unembed_w``), times the μP
+    ``lm_head_multiplier``."""
+    return scaled(unembed(w, x, cfg.vocab), cfg.lm_head_multiplier)
 
 
 def loss_fn(cfg: ModelConfig, params, batch: dict,
@@ -113,8 +153,7 @@ def loss_fn(cfg: ModelConfig, params, batch: dict,
 
         def chunk(carry, inp):
             xx, tt = inp
-            logits = unembed(w, xx, cfg.vocab)
-            l, _ = cross_entropy(logits, tt)
+            l, _ = cross_entropy(logits(cfg, w, xx), tt)
             return carry + l, None
 
         tot, _ = jax.lax.scan(chunk, jnp.zeros((), jnp.float32),
@@ -124,8 +163,7 @@ def loss_fn(cfg: ModelConfig, params, batch: dict,
         loss = tot / nc
         metrics = {"ce": loss, "z_loss": jnp.zeros((), jnp.float32)}
     else:
-        logits = unembed(w, x, cfg.vocab)
-        loss, metrics = cross_entropy(logits, batch["targets"])
+        loss, metrics = cross_entropy(logits(cfg, w, x), batch["targets"])
     loss = loss + aux_weight * aux
     metrics.update(loss=loss, moe_aux=aux)
     return loss, metrics
@@ -144,11 +182,11 @@ def _abstract_layer_cache(cfg: ModelConfig, batch: int, seq_len: int):
         c["k"] = jax.ShapeDtypeStruct(kv, dt)
         c["v"] = jax.ShapeDtypeStruct(kv, dt)
     if cfg.has_ssm:
-        w = cfg.conv_width - 1
+        w, gn = cfg.conv_width - 1, cfg.ssm_groups * cfg.ssm_state
         c["conv"] = {
             "x": jax.ShapeDtypeStruct((batch, w, cfg.d_inner), dt),
-            "B": jax.ShapeDtypeStruct((batch, w, cfg.ssm_state), dt),
-            "C": jax.ShapeDtypeStruct((batch, w, cfg.ssm_state), dt),
+            "B": jax.ShapeDtypeStruct((batch, w, gn), dt),
+            "C": jax.ShapeDtypeStruct((batch, w, gn), dt),
         }
         c["state"] = jax.ShapeDtypeStruct(
             (batch, cfg.ssm_heads, cfg.ssm_headdim, cfg.ssm_state),
@@ -213,28 +251,17 @@ def grow_cache(cfg: ModelConfig, cache: dict, prefill_len: int,
 
 def _block_decode(cfg: ModelConfig, p: dict, x: jax.Array, cache: dict,
                   pos: jax.Array):
-    h = rmsnorm(x, p["norm1"])
-    new_cache = dict(cache)
-    outs = []
-    if cfg.has_attention:
-        a, nk, nv = attn.attention_decode(cfg, p["attn"], h,
-                                          cache["k"], cache["v"], pos)
-        new_cache["k"], new_cache["v"] = nk, nv
-        outs.append(a)
-    if cfg.has_ssm:
-        s, nconv, nstate = ssm_mod.ssd_decode(cfg, p["ssm"], h,
-                                              cache["conv"], cache["state"])
-        new_cache["conv"], new_cache["state"] = nconv, nstate
-        outs.append(s)
-    mix = outs[0] if len(outs) == 1 else (outs[0] + outs[1]) * 0.5
-    x = x + mix
-    if cfg.d_ff or cfg.n_experts:
-        h2 = rmsnorm(x, p["norm2"])
-        if cfg.n_experts:
-            y, _ = moe_mod.moe_layer(cfg, p["moe"], h2)
-        else:
-            y = mlp(p["mlp"], h2, cfg.mlp_gated)
-        x = x + y
+    def attn_fn(pa, h):
+        a, k, v = attn.attention_decode(cfg, pa, h, cache["k"], cache["v"],
+                                        pos)
+        return a, {"k": k, "v": v}
+
+    def ssm_fn(ps, h):
+        s, conv, state = ssm_mod.ssd_decode(cfg, ps, h, cache["conv"],
+                                            cache["state"])
+        return s, {"conv": conv, "state": state}
+
+    x, _, new_cache = _block(cfg, p, x, attn_fn, ssm_fn)
     return x, new_cache
 
 
@@ -243,7 +270,7 @@ def decode_step(cfg: ModelConfig, params, cache, tokens: jax.Array,
     """One serve step: tokens (B, 1) int32, pos scalar int32.
 
     Returns (logits (B, vocab), new_cache)."""
-    x = embed_tokens(params["embed"], tokens).astype(jnp.dtype(cfg.act_dtype))
+    x = _embed(cfg, params, tokens)
 
     def body(h, inp):
         lp, lc = inp
@@ -253,49 +280,29 @@ def decode_step(cfg: ModelConfig, params, cache, tokens: jax.Array,
     x, new_cache = jax.lax.scan(body, x, (params["layers"], cache),
                                 unroll=cfg.scan_unroll)
     x = rmsnorm(x, params["final_norm"])
-    logits = unembed(_unembed_w(cfg, params), x[:, 0], cfg.vocab)
-    return logits, new_cache
+    return logits(cfg, _unembed_w(cfg, params), x[:, 0]), new_cache
 
 
 def _block_prefill(cfg: ModelConfig, p: dict, x: jax.Array,
                    positions: jax.Array):
     """block() that also emits the decode cache (no double compute)."""
-    h = rmsnorm(x, p["norm1"])
-    cache = {}
-    outs = []
-    if cfg.has_attention:
-        a, (k, v) = attn.attention(cfg, p["attn"], h, positions,
-                                   return_cache=True)
-        cache["k"], cache["v"] = k, v
-        outs.append(a)
-    if cfg.has_ssm:
-        s_out, (state, conv) = ssm_mod.ssd_forward(cfg, p["ssm"], h,
-                                                   return_state=True)
-        cache["state"], cache["conv"] = state, conv
-        outs.append(s_out)
-    mix = outs[0] if len(outs) == 1 else (outs[0] + outs[1]) * 0.5
-    x = x + mix
-    if cfg.d_ff or cfg.n_experts:
-        h2 = rmsnorm(x, p["norm2"])
-        if cfg.n_experts:
-            y, _ = moe_mod.moe_layer(cfg, p["moe"], h2)
-        else:
-            y = mlp(p["mlp"], h2, cfg.mlp_gated)
-        x = x + y
-    x = constrain(x, ("batch", "seq_sp" if cfg.sp else None,
-                      "act_embed"))
-    return x, cache
+    def attn_fn(pa, h):
+        a, (k, v) = attn.attention(cfg, pa, h, positions, return_cache=True)
+        return a, {"k": k, "v": v}
+
+    def ssm_fn(ps, h):
+        s, (state, conv) = ssm_mod.ssd_forward(cfg, ps, h, return_state=True)
+        return s, {"state": state, "conv": conv}
+
+    x, _, cache = _block(cfg, p, x, attn_fn, ssm_fn)
+    return _constrain_residual(cfg, x), cache
 
 
 def prefill(cfg: ModelConfig, params, batch: dict):
     """Full-sequence pass building the decode cache.
 
     Returns (last-position logits (B, vocab), cache)."""
-    if "embeddings" in batch:
-        x = batch["embeddings"].astype(jnp.dtype(cfg.act_dtype))
-    else:
-        x = embed_tokens(params["embed"], batch["tokens"])
-        x = x.astype(jnp.dtype(cfg.act_dtype))
+    x = _inputs(cfg, params, batch)
     s = x.shape[1]
     positions = jnp.arange(s, dtype=jnp.int32)   # uniform across batch
 
@@ -305,5 +312,4 @@ def prefill(cfg: ModelConfig, params, batch: dict):
     x, cache = jax.lax.scan(body, x, params["layers"],
                             unroll=cfg.scan_unroll)
     x = rmsnorm(x, params["final_norm"])
-    logits = unembed(_unembed_w(cfg, params), x[:, -1], cfg.vocab)
-    return logits, cache
+    return logits(cfg, _unembed_w(cfg, params), x[:, -1]), cache

@@ -63,23 +63,29 @@ def _moe_specs(cfg: ModelConfig) -> dict:
 
 
 def _ssm_specs(cfg: ModelConfig) -> dict:
-    d, din, n, h = cfg.d_model, cfg.d_inner, cfg.ssm_state, cfg.ssm_heads
+    d, din, h = cfg.d_model, cfg.d_inner, cfg.ssm_heads
+    gn = cfg.ssm_groups * cfg.ssm_state         # B and C: G groups of N
     w = cfg.conv_width
-    return {
+    s = {
         "w_z": ParamSpec((d, din), ("embed", "ssm_inner"), "fanin"),
         "w_x": ParamSpec((d, din), ("embed", "ssm_inner"), "fanin"),
-        "w_B": ParamSpec((d, n), ("embed", None), "fanin"),
-        "w_C": ParamSpec((d, n), ("embed", None), "fanin"),
+        "w_B": ParamSpec((d, gn), ("embed", None), "fanin"),
+        "w_C": ParamSpec((d, gn), ("embed", None), "fanin"),
         "w_dt": ParamSpec((d, h), ("embed", "ssm_heads"), "fanin"),
         "conv_x": ParamSpec((w, din), (None, "ssm_inner"), "fanin"),
-        "conv_B": ParamSpec((w, n), (None, None), "fanin"),
-        "conv_C": ParamSpec((w, n), (None, None), "fanin"),
+        "conv_B": ParamSpec((w, gn), (None, None), "fanin"),
+        "conv_C": ParamSpec((w, gn), (None, None), "fanin"),
         "A_log": ParamSpec((h,), ("ssm_heads",), "ones"),
         "D": ParamSpec((h,), ("ssm_heads",), "ones"),
         "dt_bias": ParamSpec((h,), ("ssm_heads",), "zeros"),
         "norm": ParamSpec((din,), ("ssm_inner",), "ones"),
         "out_proj": ParamSpec((din, d), ("ssm_inner", "embed"), "fanin"),
     }
+    if cfg.conv_bias:
+        s["conv_x_bias"] = ParamSpec((din,), ("ssm_inner",), "zeros")
+        s["conv_B_bias"] = ParamSpec((gn,), (None,), "zeros")
+        s["conv_C_bias"] = ParamSpec((gn,), (None,), "zeros")
+    return s
 
 
 def layer_specs(cfg: ModelConfig) -> dict:

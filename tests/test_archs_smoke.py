@@ -73,7 +73,7 @@ def test_decode_step(arch):
 
 @pytest.mark.parametrize("arch", ["llama3_8b", "granite_20b", "qwen3_14b",
                                   "mamba2_1p3b", "hymba_1p5b", "kimi_k2_1t",
-                                  "musicgen_medium"])
+                                  "musicgen_medium", "falcon_h1_34b"])
 def test_prefill_decode_consistency(arch):
     """Decode from a prefill cache == full forward (the serving invariant)."""
     cfg = get_config(arch).reduced()
@@ -82,8 +82,7 @@ def test_prefill_decode_consistency(arch):
     params = M.init_params(cfg, RNG)
     toks = jax.random.randint(RNG, (B, S + 1), 0, cfg.vocab)
     x, _ = M.forward(cfg, params, {"tokens": toks}, train=False)
-    from repro.models.layers import unembed
-    want = unembed(M._unembed_w(cfg, params), x[:, -1], cfg.vocab)
+    want = M.logits(cfg, M._unembed_w(cfg, params), x[:, -1])
     _, cache = M.prefill(cfg, params, {"tokens": toks[:, :S]})
     cache = M.grow_cache(cfg, cache, S, S + 4)
     got, _ = M.decode_step(cfg, params, cache, toks[:, S:S + 1],
@@ -100,6 +99,7 @@ def test_param_count_matches_published(arch):
         "qwen3_14b": 14.8e9, "mamba2_1p3b": 1.35e9, "internvl2_76b": 70e9,
         "kimi_k2_1t": 1.03e12, "grok1_314b": 314e9,
         "musicgen_medium": 1.4e9, "hymba_1p5b": 1.52e9,
+        "falcon_h1_34b": 33.6e9,
     }
     n = get_config(arch).n_params()
     assert abs(n - published[arch]) / published[arch] < 0.07, n

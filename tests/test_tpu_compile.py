@@ -75,6 +75,11 @@ CASES = {
     "c4_statescan": (lambda a, s: ops.chunk_scan_state(a, s, axis=1,
                                                        mode="kernel"),
                      [((4, 2, 64), F32), ((4, 2, 64, 64, 128), F32)]),
+    # Falcon-H1-34B prefill, one 16,384-token row: 128 chunks of 32 heads,
+    # a (128, 256) state payload
+    "c4_statescan_falcon_h1": (
+        lambda a, s: ops.chunk_scan_state(a, s, axis=1, mode="kernel"),
+        [((1, 128, 32), F32), ((1, 128, 32, 128, 256), F32)]),
     "c6_flashattn": (lambda q, k, v: ops.flash_attention(q, k, v,
                                                          mode="kernel"),
                      [((1, 32, 2048, 128), BF16)] * 3),
