@@ -40,7 +40,8 @@ DEFAULT_RULES: Rules = {
     "q_heads":    [("model",), None],
     "kv_heads":   [("model",), None],
     "head_dim":   [None],
-    "cache_seq":  [("model",), None],           # decode KV cache: seq over TP
+    "cache_seq":  [("model",), None],           # KV cache seq over TP, where
+                                                # kv_heads does not take it
     # -- params --------------------------------------------------------------
     "embed":      [("data",), None],            # FSDP dim (gathered per layer)
     "embed_nofsdp": [None],

@@ -7,11 +7,14 @@ impl='chunked'  — XLA online-softmax over q chunks: the flash-attention
 impl='kernel'   — c6_flashattn Pallas kernel (TPU target; 'interpret' in
                   kernel tests).
 
-Decode: one new token against a KV cache whose *sequence* dim is sharded
-over the `model` mesh axis (DESIGN.md §6 — kv-head counts never divide a
-16-way TP axis, seq does). The softmax/weighted-sum reductions over the
-sharded seq dim compile to the partial-reduce + small all-reduce pattern
-(flash-decode); the roofline table verifies the collective bytes.
+Decode: one new token against a KV cache held as (B, KV, T, hd), the
+layout the decode dots contract in. Its KV-head dim takes the `model`
+mesh axis where the head count divides it, else its *sequence* dim does
+(DESIGN.md §6 — kv-head counts never divide a 16-way TP axis, seq does);
+the softmax/weighted-sum reductions over a sharded seq dim compile to the
+partial-reduce + small all-reduce pattern (flash-decode). The step's own
+key and value are one more softmax term beside the cache, which the step
+reads and does not write: the caller stores the new rows.
 """
 from __future__ import annotations
 
@@ -128,7 +131,8 @@ def attention(cfg: ModelConfig, p: dict, x: jax.Array,
     out = jnp.einsum("bshk,hkd->bsd", o, p["wo"])
     if return_cache:
         t = cache_len(cfg, q.shape[1])
-        return out, (k[:, -t:], v[:, -t:])
+        return out, (jnp.swapaxes(k[:, -t:], 1, 2),
+                     jnp.swapaxes(v[:, -t:], 1, 2))
     return out
 
 
@@ -141,36 +145,52 @@ def cache_len(cfg: ModelConfig, seq_len: int) -> int:
     return min(seq_len, cfg.swa_window) if cfg.swa_window else seq_len
 
 
+def write_slot(cfg: ModelConfig, pos: jax.Array, t: int) -> jax.Array:
+    """Cache slot of position ``pos`` in a cache of ``t`` slots: ``pos``,
+    or ``pos % t`` in the SWA rolling window."""
+    return jnp.mod(pos, t) if cfg.swa_window else pos
+
+
 def attention_decode(cfg: ModelConfig, p: dict, x: jax.Array,
                      k_cache: jax.Array, v_cache: jax.Array,
                      pos: jax.Array):
-    """x: (B, 1, D); caches (B, T, KV, hd); pos: scalar current position.
+    """x: (B, 1, D); caches (B, KV, T, hd) holding the positions before
+    ``pos``; pos: scalar current position.
 
-    Returns (out (B,1,D), new_k_cache, new_v_cache).
+    The step's own key and value join the softmax as one more term, so
+    the caches are only read. Old slots count where filled
+    (``slot < min(pos, T)``) and not at the write slot, whose position
+    the SWA window drops.
+
+    Returns (out (B,1,D), new k row, new v row), rows (B, KV, 1, hd) for
+    the caller to store at ``write_slot``.
     """
     b = x.shape[0]
-    t = k_cache.shape[1]
+    t = k_cache.shape[2]
     positions = jnp.full((b, 1), pos, jnp.int32)
     q, k, v = _project_qkv(cfg, p, x, positions)
-
-    slot = jnp.mod(pos, t) if cfg.swa_window else pos
-    k_cache = jax.lax.dynamic_update_slice_in_dim(k_cache, k, slot, axis=1)
-    v_cache = jax.lax.dynamic_update_slice_in_dim(v_cache, v, slot, axis=1)
 
     h, kvh, hd = q.shape[2], k.shape[2], q.shape[3]
     g = h // kvh
     qg = q.reshape(b, kvh, g, hd)
-    logits = jnp.einsum("bkgd,btkd->bkgt", qg, k_cache).astype(jnp.float32)
+    k_new, v_new = k.reshape(b, kvh, hd), v.reshape(b, kvh, hd)
+    logits = jnp.einsum("bkgd,bktd->bkgt", qg, k_cache).astype(jnp.float32)
     logits *= hd ** -0.5
+    logit_new = jnp.einsum("bkgd,bkd->bkg", qg,
+                           k_new).astype(jnp.float32) * hd ** -0.5
 
-    slot_idx = jnp.arange(t)[None, :]                      # (1, T)
-    if cfg.swa_window:
-        valid = slot_idx <= jnp.minimum(pos, t - 1)        # filled slots
-    else:
-        valid = slot_idx <= pos
-    logits = jnp.where(valid[:, None, None, :], logits, NEG_INF)
-    w = jax.nn.softmax(logits, axis=-1)
-    o = jnp.einsum("bkgt,btkd->bkgd", w.astype(x.dtype), v_cache)
-    o = o.reshape(b, 1, h, hd)
+    slot_idx = jnp.arange(t)
+    valid = ((slot_idx < jnp.minimum(pos, t))
+             & (slot_idx != write_slot(cfg, pos, t)))
+    logits = jnp.where(valid, logits, NEG_INF)
+    m = jnp.maximum(jnp.max(logits, axis=-1), logit_new)   # (B,KV,G)
+    e = jnp.exp(logits - m[..., None])
+    e_new = jnp.exp(logit_new - m)
+    denom = jnp.sum(e, axis=-1) + e_new
+    o = jnp.einsum("bkgt,bktd->bkgd", (e / denom[..., None]).astype(x.dtype),
+                   v_cache, preferred_element_type=jnp.float32)
+    o = o + jnp.einsum("bkg,bkd->bkgd", e_new / denom, v_new,
+                       preferred_element_type=jnp.float32)
+    o = o.astype(x.dtype).reshape(b, 1, h, hd)
     out = jnp.einsum("bshk,hkd->bsd", o, p["wo"])
-    return out, k_cache, v_cache
+    return out, k_new[:, :, None], v_new[:, :, None]

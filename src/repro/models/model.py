@@ -178,7 +178,7 @@ def _abstract_layer_cache(cfg: ModelConfig, batch: int, seq_len: int):
     c = {}
     if cfg.has_attention:
         t = attn.cache_len(cfg, seq_len)
-        kv = (batch, t, cfg.n_kv_heads, cfg.head_dim)
+        kv = (batch, cfg.n_kv_heads, t, cfg.head_dim)
         c["k"] = jax.ShapeDtypeStruct(kv, dt)
         c["v"] = jax.ShapeDtypeStruct(kv, dt)
     if cfg.has_ssm:
@@ -205,7 +205,7 @@ def abstract_cache(cfg: ModelConfig, batch: int, seq_len: int):
 def cache_logical_axes(cfg: ModelConfig):
     axes = {}
     if cfg.has_attention:
-        kvax = ("layers", "batch", "cache_seq", "kv_heads", "head_dim")
+        kvax = ("layers", "batch", "kv_heads", "cache_seq", "head_dim")
         axes["k"] = kvax
         axes["v"] = kvax
     if cfg.has_ssm:
@@ -227,33 +227,38 @@ def grow_cache(cfg: ModelConfig, cache: dict, prefill_len: int,
                capacity: int) -> dict:
     """Make a prefill cache decodable up to `capacity` positions.
 
-    Non-SWA: zero-pad the seq dim. SWA: the rolling cache is already at
-    window size; rotate entries so absolute position p sits at slot
-    p % window (the decode-side invariant)."""
+    Non-SWA: zero-pad the positions dim (-2 of (..., KV, T, hd)). SWA:
+    the rolling cache is already at window size; rotate entries so
+    absolute position p sits at slot p % window (the decode-side
+    invariant)."""
     if not cfg.has_attention:
         return cache
     new = dict(cache)
     for key in ("k", "v"):
         c = cache[key]
         if cfg.swa_window:
-            w = c.shape[-3]
+            w = c.shape[-2]
             if prefill_len > w:
-                c = jnp.roll(c, shift=prefill_len % w, axis=-3)
+                c = jnp.roll(c, shift=prefill_len % w, axis=-2)
         else:
-            pad = capacity - c.shape[-3]
+            pad = capacity - c.shape[-2]
             if pad > 0:
                 widths = [(0, 0)] * c.ndim
-                widths[-3] = (0, pad)
+                widths[-2] = (0, pad)
                 c = jnp.pad(c, widths)
         new[key] = c
     return new
 
 
 def _block_decode(cfg: ModelConfig, p: dict, x: jax.Array, cache: dict,
-                  pos: jax.Array):
+                  kv: dict, layer: jax.Array, pos: jax.Array):
+    """One layer's decode. ``cache`` holds the layer's SSM entries; ``kv``
+    the whole stacked (L, B, KV, T, hd) K/V, of which layer ``layer`` is
+    read in place. Returns (x, SSM entries and the new K/V rows)."""
     def attn_fn(pa, h):
-        a, k, v = attn.attention_decode(cfg, pa, h, cache["k"], cache["v"],
-                                        pos)
+        k, v = (jax.lax.dynamic_index_in_dim(kv[n], layer, keepdims=False)
+                for n in ("k", "v"))
+        a, k, v = attn.attention_decode(cfg, pa, h, k, v, pos)
         return a, {"k": k, "v": v}
 
     def ssm_fn(ps, h):
@@ -269,16 +274,27 @@ def decode_step(cfg: ModelConfig, params, cache, tokens: jax.Array,
                 pos: jax.Array):
     """One serve step: tokens (B, 1) int32, pos scalar int32.
 
+    The layer scan carries only the entries rewritten whole every step
+    (SSM state and convolution window). The stacked K/V is closed over,
+    read in place layer by layer, and gets the step's L new rows with
+    one write per K and V after the loop.
+
     Returns (logits (B, vocab), new_cache)."""
     x = _embed(cfg, params, tokens)
+    kv = {n: cache[n] for n in ("k", "v") if n in cache}
+    rest = {n: c for n, c in cache.items() if n not in kv}
+    layers = jnp.arange(cfg.n_layers) if kv else None
 
     def body(h, inp):
-        lp, lc = inp
-        h, nc = _block_decode(cfg, lp, h, lc, pos)
-        return h, nc
+        lp, lc, i = inp
+        return _block_decode(cfg, lp, h, lc, kv, i, pos)
 
-    x, new_cache = jax.lax.scan(body, x, (params["layers"], cache),
+    x, new_cache = jax.lax.scan(body, x, (params["layers"], rest, layers),
                                 unroll=cfg.scan_unroll)
+    for n, c in kv.items():
+        slot = attn.write_slot(cfg, pos, c.shape[-2])
+        new_cache[n] = jax.lax.dynamic_update_slice_in_dim(
+            c, new_cache[n], slot, axis=3)
     x = rmsnorm(x, params["final_norm"])
     return logits(cfg, _unembed_w(cfg, params), x[:, 0]), new_cache
 

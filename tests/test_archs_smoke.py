@@ -1,5 +1,6 @@
 """Per-arch smoke: reduced config, one forward/train step, shapes + no NaNs."""
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -89,6 +90,74 @@ def test_prefill_decode_consistency(arch):
                            jnp.int32(S))
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("arch,prefill_len,steps", [
+    ("llama3_8b", S, 4), ("granite_20b", S, 4), ("qwen3_14b", S, 4),
+    ("mamba2_1p3b", S, 4), ("falcon_h1_34b", S, 5),
+    # SWA window 32: the first step writes slot 0 (the wrap) and drops
+    # position 0; a 48-token prefill comes back rolled by grow_cache
+    ("hymba_1p5b", S, 6), ("hymba_1p5b", S + 16, 6)])
+def test_multi_step_decode_matches_forward(arch, prefill_len, steps):
+    """Every step of a decode from a prefill cache gives the logits the
+    full forward gives at that position: the step's own key and value
+    count once, the cache's filled slots count, and under SWA the slot a
+    step overwrites no longer counts."""
+    cfg = get_config(arch).reduced()
+    params = M.init_params(cfg, RNG)
+    n = prefill_len + steps
+    toks = jax.random.randint(RNG, (B, n), 0, cfg.vocab)
+    _, cache = M.prefill(cfg, params, {"tokens": toks[:, :prefill_len]})
+    cache = M.grow_cache(cfg, cache, prefill_len, n)
+    x, _ = M.forward(cfg, params, {"tokens": toks}, train=False)
+    want = M.logits(cfg, M._unembed_w(cfg, params), x)
+    step = jax.jit(functools.partial(M.decode_step, cfg))
+    for pos in range(prefill_len, n):
+        got, cache = step(params, cache, toks[:, pos:pos + 1],
+                          jnp.int32(pos))
+        np.testing.assert_allclose(np.asarray(got),
+                                   np.asarray(want[:, pos]),
+                                   rtol=2e-4, atol=2e-4, err_msg=str(pos))
+
+
+@pytest.mark.parametrize("arch,prefill_len", [
+    ("falcon_h1_34b", S), ("hymba_1p5b", S + 16), ("llama3_8b", S)])
+def test_kv_cache_layout_agrees(arch, prefill_len):
+    """abstract_cache, prefill's cache, grow_cache and cache_logical_axes
+    agree on (L, B, KV, T, hd); growing pads (or, under SWA, rolls) the
+    positions axis and leaves the SSM entries as they were."""
+    cfg = get_config(arch).reduced()
+    params = M.init_params(cfg, RNG)
+    capacity = prefill_len + 8
+    toks = jax.random.randint(RNG, (B, prefill_len), 0, cfg.vocab)
+    _, cache = M.prefill(cfg, params, {"tokens": toks})
+    grown = M.grow_cache(cfg, cache, prefill_len, capacity)
+    want = M.abstract_cache(cfg, B, capacity)
+    t = cfg.swa_window or capacity
+    kv = (cfg.n_layers, B, cfg.n_kv_heads, t, cfg.head_dim)
+    assert want["k"].shape == want["v"].shape == kv
+    assert jax.tree.map(lambda a: (a.shape, a.dtype), grown) == \
+        jax.tree.map(lambda a: (a.shape, a.dtype), want)
+    axes = M.cache_logical_axes(cfg)
+    assert axes["k"] == axes["v"] == (
+        "layers", "batch", "kv_heads", "cache_seq", "head_dim")
+    assert jax.tree.structure(axes, is_leaf=lambda a: isinstance(a, tuple)) \
+        == jax.tree.structure(want)
+    for n in ("k", "v"):
+        c, g = np.asarray(cache[n]), np.asarray(grown[n])
+        if cfg.swa_window:
+            # prefill keeps the window's last t positions in order; after
+            # growing, position p sits at slot p % t
+            first = prefill_len - t
+            for p in range(first, prefill_len):
+                np.testing.assert_array_equal(g[:, :, :, p % t],
+                                              c[:, :, :, p - first])
+        else:
+            np.testing.assert_array_equal(g[:, :, :, :prefill_len], c)
+            assert not g[:, :, :, prefill_len:].any()
+    for n in set(cache) - {"k", "v"}:
+        for a, b in zip(jax.tree.leaves(cache[n]), jax.tree.leaves(grown[n])):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 @pytest.mark.parametrize("arch", ARCHS)

@@ -7,17 +7,24 @@ layouts and VMEM footprints — at no chip time. The topology is
 described inside a fixture, never at import: only one process at a time
 may load the TPU library.
 """
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
 import repro.kernels  # noqa: F401 — registers the instruction set
+from repro.configs import get_config
 from repro.core import isa
 from repro.core.program import _BLOCK_COL_CANDIDATES
+from repro.distributed.sharding import tree_shardings
 from repro.kernels import ops
+from repro.launch.mesh import make_mesh
+from repro.models import model as M
+from repro.models.params import abstract_params, logical_axes
 
 
 @pytest.fixture(scope="module")
@@ -108,3 +115,71 @@ def test_widest_negotiable_block_compiles(one_chip):
         s, x, b, t, c, block_rows=rows, block_cols=cols),
         sc, vec, vec, sc, vec)
     assert _kernel_in(exe)
+
+
+def _computations(text: str) -> dict:
+    """Compiled HLO text → {computation name: [(opcode, result shapes,
+    line)]} for its top-level instructions; the entry is under "ENTRY"."""
+    comps, cur = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(ENTRY )?%(\S+) \(.*\{$", line)
+        if head:
+            cur = comps.setdefault("ENTRY" if head.group(1) else head.group(2),
+                                   [])
+            continue
+        ins = re.match(r"\s+(?:ROOT )?%\S+ = (.*)$", line)
+        if cur is None or not ins:
+            continue
+        rest = ins.group(1)
+        if rest.startswith("("):         # tuple shape: up to its paren
+            depth = 0
+            for i, ch in enumerate(rest):
+                depth += {"(": 1, ")": -1}.get(ch, 0)
+                if depth == 0:
+                    break
+            shape, rest = rest[:i + 1], rest[i + 1:].lstrip()
+        else:
+            shape, _, rest = rest.partition(" ")
+        op = re.match(r"([\w\-]+)\(", rest)
+        dims = [tuple(int(d) for d in m.split(",") if d)
+                for m in re.findall(r"\w+\[([\d,]*)\]", shape)]
+        cur.append((op.group(1) if op else "", dims, line))
+    return comps
+
+
+def test_falcon_decode_reads_kv_in_place(topo):
+    """The served Falcon-H1 stage's decode step (16 rows × 20,480 slots):
+    the layer loop moves no K/V-sized array, it reads each layer's K and V
+    straight into the attention dots; the step copies each of K and V once
+    into its (undonated) output and writes the new rows there."""
+    cfg = get_config("falcon-h1-34b-stage")
+    b, t = 16, 20480
+    mesh = make_mesh((1, 1), ("data", "model"), devices=topo.devices[:1])
+    one = NamedSharding(mesh, PartitionSpec())
+    with jax.set_mesh(mesh):
+        sh = tree_shardings(logical_axes(cfg), abstract_params(cfg), mesh)
+        params = jax.tree.map(
+            lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+            abstract_params(cfg), sh)
+        cache = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one),
+            M.abstract_cache(cfg, b, t))
+        exe = jax.jit(lambda p, c, x, i: M.decode_step(cfg, p, c, x, i)).lower(
+            params, cache, jax.ShapeDtypeStruct((b, 1), I32, sharding=one),
+            jax.ShapeDtypeStruct((), I32, sharding=one)).compile()
+    stacked = cache["k"].shape
+    layer = math.prod(stacked[1:])
+    comps = _computations(exe.as_text())
+    bodies = {m for _, _, line in comps["ENTRY"] if " while(" in line
+              for m in re.findall(r"body=%([\w.\-]+)", line)}
+    assert bodies, "no layer loop in the decode step"
+    views = {"parameter", "get-tuple-element", "tuple", "bitcast"}
+    moved = [line.strip()[:160] for name in bodies
+             for op, dims, line in comps[name]
+             if op not in views and any(math.prod(d) >= layer for d in dims)]
+    assert not moved, moved
+    copies = [line for op, dims, line in comps["ENTRY"]
+              if op.startswith("copy") and stacked in dims]
+    assert len(copies) <= 2, copies
+    assert sum("%c__k__" in line for line in copies) <= 1
+    assert sum("%c__v__" in line for line in copies) <= 1
